@@ -25,7 +25,7 @@ directly comparable:
 
 ``--seed-cache`` additionally persists each swept shape's measured-best
 tiling into the autotune cache (``REPRO_AUTOTUNE_CACHE``, default
-``results/autotune.json``) — the file ``resolve_block_sizes`` consults
+``results/autotune.json``) — the file ``resolve_block_b`` consults
 at serve time.  CI seeds the cache on the interpret backend this way;
 on a real TPU the same command measures compiled kernels.
 
@@ -43,14 +43,24 @@ import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import jax
 import jax.numpy as jnp
 
-# TPU v5e HBM peak — the same constant roofline.py uses for the
-# dry-run three-term model; bench entries carry achieved bytes/s and
-# the roofline ingest divides by this
-HBM_BW = 819e9
+from benchmarks.roofline import TARGET_KIND, chip_peaks
+
+
+def hbm_peak() -> float:
+    """HBM bytes/s the entries' ``peak_fraction`` divides by: the
+    measured device's published peak (an unknown kind raises), or,
+    under the Pallas interpreter, the target chip's — interpreter
+    timings make that fraction a ratio between rungs, not a share of
+    any device."""
+    from repro.kernels import autotune
+    kind = (TARGET_KIND if autotune.backend_name() == "interpret"
+            else jax.devices()[0].device_kind)
+    return chip_peaks(kind)["hbm_bw"]
 
 # (b, k, d, h) swept by default: a serving-ish bag shape and a smaller
 # awkward-D shape (exercises the 128-aligned edge-tile path)
@@ -98,7 +108,7 @@ def bench_shape(b: int, k: int, d: int, h: int, *, iters: int,
                 seed_cache: bool) -> list[dict]:
     from repro.kernels import autotune
     from repro.kernels.bag_matmul.kernel import bag_matmul_pallas
-    from repro.kernels.bag_matmul.ops import _bm_auto_block_b
+    from repro.kernels.bag_matmul.ops import _auto_block_h, _bm_auto_block_b
     from repro.kernels.dequant_bag.kernel import (
         bag_grad_pallas,
         dequant_bag_pallas,
@@ -107,7 +117,6 @@ def bench_shape(b: int, k: int, d: int, h: int, *, iters: int,
     from repro.kernels.dequant_bag.ops import (
         _VMEM_SCRATCH_BUDGET,
         _auto_block_b,
-        _auto_block_d,
     )
 
     payload, scales, idx, weights, w3, g = _case(b, k, d, h)
@@ -125,56 +134,55 @@ def bench_shape(b: int, k: int, d: int, h: int, *, iters: int,
             "speedup": us_a / us_m if us_m > 0 else 1.0,
             "bytes_moved": int(nbytes),
             "achieved_gbs": nbytes / us * 1e6 / 1e9 if us > 0 else 0.0,
-            "peak_fraction": (nbytes / (us * 1e-6)) / HBM_BW
+            "peak_fraction": (nbytes / (us * 1e-6)) / hbm_peak()
             if us > 0 else 0.0,
         })
 
-    def tune(kernel, dtype, run, candidates, analytic, nbytes, hh=0,
-             extra=""):
+    def tune(kernel, dtype, run, candidates, analytic, names, nbytes,
+             hh=0, extra=""):
         """Time the analytic pick, sweep the candidates (analytic is
         always among them, so best <= analytic), optionally persist
-        the winner."""
+        the winner under the block ``names``."""
         cands = [tuple(c) for c in candidates]
         if tuple(analytic) not in cands:
             cands.insert(0, tuple(analytic))
         res = autotune.sweep(run, cands, iters=iters)
         us_a = next(r["us"] for r in res["sweep"]
-                    if (r["block_b"], r["block_d"]) == tuple(analytic))
+                    if tuple(r["blocks"]) == tuple(analytic))
         if us_a is None:  # analytic pick failed to launch: best wins
             us_a = res["best_us"]
         entry(kernel, dtype, analytic, us_a, res["best"],
               res["best_us"], nbytes, hh)
         if seed_cache:
-            autotune.store(kernel, dtype, b, k, d, res["best"][0],
-                           res["best"][1], res["best_us"], extra=extra)
+            autotune.store(kernel, dtype, b, k, d,
+                           dict(zip(names, res["best"])), res["best_us"],
+                           extra=extra)
         return res
 
     # -- rowgrid baseline: no tiling, no pipeline ----------------------
     us = autotune.time_us(
         lambda: dequant_bag_pallas_rowgrid(payload, scales, idx,
                                            weights), iters=iters)
-    entry("dequant_bag_rowgrid", "int8", [1, d], us, [1, d], us,
+    entry("dequant_bag_rowgrid", "int8", [1], us, [1], us,
           _bytes_dequant(b, k, d, itemsize))
 
     # -- tiled + pipelined forward -------------------------------------
-    # pure analytic picks (the private helpers), NOT resolve_block_sizes:
+    # pure analytic picks (the private helpers), NOT resolve_block_b:
     # that would consult the very cache this bench may have just seeded
-    ad = _auto_block_d(d)
-    analytic = (_auto_block_b(b, k, ad, itemsize, _VMEM_SCRATCH_BUDGET),
-                ad)
-    cands = autotune.candidate_tilings(b, k, d, itemsize)
+    analytic = (_auto_block_b(b, k, d, itemsize, _VMEM_SCRATCH_BUDGET),)
     tune("dequant_bag", "int8",
-         lambda bb, bd: lambda: dequant_bag_pallas(
-             payload, scales, idx, weights, block_b=bb, block_d=bd),
-         cands, analytic, _bytes_dequant(b, k, d, itemsize))
+         lambda bb: lambda: dequant_bag_pallas(
+             payload, scales, idx, weights, block_b=bb),
+         [(bb,) for bb in autotune.candidate_block_b(b, k, d, itemsize)],
+         analytic, ("block_b",), _bytes_dequant(b, k, d, itemsize))
 
     # -- pipelined backward scatter ------------------------------------
-    analytic_g = (_auto_block_b(b, k, ad, 4, _VMEM_SCRATCH_BUDGET), ad)
-    cands_g = autotune.candidate_tilings(b, k, d, 4)
+    analytic_g = (_auto_block_b(b, k, d, 4, _VMEM_SCRATCH_BUDGET),)
     tune("bag_grad", "float32",
-         lambda bb, bd: lambda: bag_grad_pallas(
-             g, scales, idx, weights, VOCAB, block_b=bb, block_d=bd),
-         cands_g, analytic_g, _bytes_bag_grad(b, k, d))
+         lambda bb: lambda: bag_grad_pallas(
+             g, scales, idx, weights, VOCAB, block_b=bb),
+         [(bb,) for bb in autotune.candidate_block_b(b, k, d, 4)],
+         analytic_g, ("block_b",), _bytes_bag_grad(b, k, d))
 
     # -- fusion before/after -------------------------------------------
     w2 = w3.reshape(k * d, h)
@@ -190,19 +198,19 @@ def bench_shape(b: int, k: int, d: int, h: int, *, iters: int,
 
     us_u = autotune.time_us(
         lambda: unfused(payload, scales, idx, weights), iters=iters)
-    entry("unfused_bag_matmul", "int8", [1, d], us_u, [1, d], us_u,
+    entry("unfused_bag_matmul", "int8", [1], us_u, [1], us_u,
           _bytes_unfused(b, k, d, h, itemsize), hh=h)
 
-    ah = _auto_block_d(h)
+    ah = _auto_block_h(h)
     analytic_m = (_bm_auto_block_b(b, k, d, ah, itemsize), ah)
-    cands_m = [(bb, hb) for bb, hb in
-               autotune.candidate_tilings(b, k, h, itemsize)
-               if hb <= h]
+    cands_m = [(bb, bh)
+               for bb in autotune.candidate_block_b(b, k, d, itemsize)
+               for bh in sorted({ah, h})]
     tune("bag_matmul", "int8",
          lambda bb, bh: lambda: bag_matmul_pallas(
              payload, scales, idx, weights, w3, block_b=bb, block_h=bh),
-         cands_m, analytic_m, _bytes_bag_matmul(b, k, d, h, itemsize),
-         hh=h, extra=f"|h={h}")
+         cands_m, analytic_m, ("block_b", "block_h"),
+         _bytes_bag_matmul(b, k, d, h, itemsize), hh=h, extra=f"|h={h}")
     return rows
 
 
@@ -220,7 +228,7 @@ def run(shapes=DEFAULT_SHAPES, iters: int = 2,
         "backend": autotune.backend_name(),
         "interpret": autotune.backend_name() == "interpret",
         "cache_path": autotune.cache_path() if seed_cache else None,
-        "hbm_peak_gbs": HBM_BW / 1e9,
+        "hbm_peak_gbs": hbm_peak() / 1e9,
         "sweep": sweep,
     }
 

@@ -2,7 +2,8 @@
 
 Runs ``repro.launch.serve --online --serve-batch ...`` over 1/2/4-way
 row-sharded host meshes (each in its own subprocess — the XLA
-host-device count must be fixed before jax initialises) and emits one
+host-device count must be fixed before jax initialises, and on a TPU
+each child needs the chip, so this process stays off JAX) and emits one
 stable-schema ``bench_qps/v1`` record per mesh size: the same contract
 as ``benchmarks/qps.py --online --serve-batch`` (PR 3), so
 ``tools/check_bench_schema.py`` validates every record and future PRs
@@ -36,11 +37,27 @@ SWEEP_KEYS = ("serve_batch", "qps", "steady_qps", "p50_us", "p95_us",
               "bytes_per_request_fp32", "bytes_per_request_packed")
 
 
+def _parent_holds_device() -> bool:
+    """True once this process has initialised an accelerator backend:
+    it then owns the chip, and a child asking for it fails or hangs
+    (CPU backends are per process and never conflict)."""
+    xb = sys.modules.get("jax._src.xla_bridge")
+    if xb is None or not xb.backends_are_initialized():
+        return False
+    import jax
+    return jax.default_backend() != "cpu"
+
+
 def serve_record(mesh: int, requests: int, serve_batch: int,
                  retier_every: int, arch: str = "dlrm-rm2",
                  retier_async: bool = False) -> dict:
     """One online micro-batched serve run in a subprocess -> its JSON
-    record (the last stdout line)."""
+    record (the last stdout line).  The parent must stay off the
+    device; this raises if it has initialised a JAX backend."""
+    if _parent_holds_device():
+        raise RuntimeError("qps_sharded: this process already holds a "
+                           "JAX backend; run the sweep before any job "
+                           "that touches a device")
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (os.path.join(REPO, "src"),

@@ -9,8 +9,8 @@ per (arch x shape x mesh):
 
 HLO_FLOPs / bytes / collective_bytes are PER-DEVICE numbers (the SPMD
 module is one device's program, trip-count-corrected by
-launch/hlo_analysis).  Hardware: TPU v5e — 197 TFLOP/s bf16, 819 GB/s
-HBM, 4 ICI links x ~50 GB/s.
+launch/hlo_analysis).  Hardware: the dry-run's target chip, TPU v5e
+(``CHIP_PEAKS`` below, keyed by jax ``device_kind``).
 
 MODEL_FLOPS uses 6*N*D (dense) / 6*N_active*D (MoE) for LM training,
 2*N*D for LM inference tokens, and analytic op counts for recsys/GNN.
@@ -32,9 +32,30 @@ import glob
 import json
 import os
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s
-ICI_BW = 4 * 50e9            # bytes/s aggregate links per chip
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+# 16 GB HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect.
+CHIP_PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9,
+                    "ici_bw": 1600e9 / 8},
+}
+# the chip the dry-run meshes and the kernels' tilings are written for
+TARGET_KIND = "TPU v5 lite"
+
+
+def chip_peaks(kind: str) -> dict:
+    """Peaks of one chip of ``kind``; a kind not in the table is an
+    error, never a default."""
+    try:
+        return CHIP_PEAKS[kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind {kind!r} "
+                         f"(known: {sorted(CHIP_PEAKS)})") from None
+
+
+PEAK_FLOPS = chip_peaks(TARGET_KIND)["flops"]
+HBM_BW = chip_peaks(TARGET_KIND)["hbm_bw"]
+ICI_BW = chip_peaks(TARGET_KIND)["ici_bw"]
 
 RESULTS = os.path.join(os.path.dirname(__file__), "..", "results",
                        "dryrun")

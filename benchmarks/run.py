@@ -139,7 +139,12 @@ def main() -> None:
                             table2_time, table3_fquant,
                             table4_combined)
 
+    # qps_sharded first: its serve children each need the device, which
+    # this process holds from the first job that touches a backend on
     jobs = {
+        "qps_sharded": lambda: qps_sharded.run(
+            requests=24 if fast else 48,
+            serve_batches=(8,) if fast else (1, 8)),
         "table2_time": lambda: table2_time.run(
             eval_batches=2 if fast else 4, shuffles=1 if fast else 2),
         "table3_fquant": lambda: table3_fquant.run(
@@ -155,9 +160,6 @@ def main() -> None:
             keep_counts=(6,) if fast else (8, 6, 4),
             finetune_steps=40 if fast else 150),
         "qps": lambda: qps.run(iters=5 if fast else 20),
-        "qps_sharded": lambda: qps_sharded.run(
-            requests=24 if fast else 48,
-            serve_batches=(8,) if fast else (1, 8)),
         "freq_error": lambda: freq_error.run(
             train_steps=100 if fast else 400),
         "hashed": lambda: hashed.run(fast=fast),
@@ -166,14 +168,18 @@ def main() -> None:
     if args.only:
         jobs = {k: v for k, v in jobs.items() if k == args.only}
 
+    failed = []
     for name, job in jobs.items():
         t0 = time.perf_counter()
         try:
             rows = job()
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 - report, run the rest
             print(f"{name},0,error={type(e).__name__}:{e}")
+            failed.append(name)
             continue
         _emit(name, t0, rows)
+    if failed:
+        raise SystemExit(f"benchmark jobs failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

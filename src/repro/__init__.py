@@ -13,4 +13,9 @@ Layers:
   repro.launch    - mesh / dryrun / train / serve entry points
 """
 
+import pathlib
+
 __version__ = "1.0.0"
+
+# the checkout this package runs from: src/repro/__init__.py -> root
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[2]

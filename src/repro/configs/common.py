@@ -17,7 +17,7 @@ prefill / decode / packed-store forward / retrieval scoring.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -363,6 +363,12 @@ def packed_specs(rows_axis):
         payload32=P(rows_axis, None), indirect=P(rows_axis))
 
 
+class DriverModel(NamedTuple):
+    model: Any
+    num_dense: int          # dense features per example (0: none)
+    lr: float               # the drivers' default training rate
+
+
 @dataclasses.dataclass
 class RecsysArch(Arch):
     model: Any                       # models.recsys.Model (full size)
@@ -373,9 +379,30 @@ class RecsysArch(Arch):
     seq_model: bool = False          # BERT4Rec batch format
     seq_len: int = 200
     lr: float = 0.01
+    chip_model: Any = None           # published widths, one chip's vocab
+    reduced: dict = dataclasses.field(default_factory=dict)  # chip cuts
+    # compressed-step learning rate (head Adam + table adagrad) the
+    # drivers train each model at: the smoke config's tuned rate, and
+    # one for the published widths, where Adam at 0.05 diverges
+    smoke_lr: float = 0.05
+    chip_lr: float = 1e-3
     name: str = ""
     family: str = "recsys"
     ruleset: str = "recsys"
+
+    def driver_model(self, chip: bool = True) -> DriverModel:
+        """(model, dense width, lr) the launch drivers run:
+        ``chip_model`` on a TPU backend where the arch has one, the
+        smoke model elsewhere (CPU tests and rehearsals) or with
+        ``chip=False``."""
+        if (chip and self.chip_model is not None
+                and jax.default_backend() == "tpu"):
+            return DriverModel(self.chip_model,
+                               self.num_dense if self.has_dense else 0,
+                               self.chip_lr)
+        return DriverModel(self.smoke_model,
+                           self.smoke_num_dense if self.has_dense else 0,
+                           self.smoke_lr)
 
     def cells(self) -> list[str]:
         return list(RECSYS_SHAPES)

@@ -3,8 +3,14 @@ bot_mlp=13-512-256-64 top_mlp=512-512-256-1 interaction=dot
 [arXiv:1906.00091].
 
 The paper's own public-dataset baseline model.  Production cardinalities
-follow the Criteo-terabyte scale (total ~266M rows x 64 dims = 68 GB fp32
+follow the Criteo-terabyte scale (total ~204M rows x 64 dims = 52 GB fp32
 -> the SHARK compression target).
+
+One 16 GB TPU v5e chip cannot hold that table, so the drivers run
+``CHIP_CFG`` there: the published widths (26 fields, dim 64, 13 dense,
+MLPs 512-256-64 and 512-512-256-1) with each field's vocabulary capped
+at ``CHIP_ROW_CAP`` = 2M rows — 13,116,632 rows, 3.36 GB in fp32
+(``REDUCED`` states the cut).  The smoke config stays for CPU tests.
 """
 
 from repro.configs.common import RecsysArch
@@ -23,6 +29,16 @@ FULL_CFG = R.DLRMConfig(cardinalities=CARDS, embed_dim=64, num_dense=13,
                         bot_mlp=(512, 256, 64),
                         top_mlp=(512, 512, 256, 1))
 
+# one chip's share of the vocabulary: the fp32 training table plus the
+# compressed step's dense (V, D) gradient must fit 16 GB of HBM
+CHIP_ROW_CAP = 2_000_000
+CHIP_CFG = R.DLRMConfig(
+    cardinalities=tuple(min(c, CHIP_ROW_CAP) for c in CARDS),
+    embed_dim=FULL_CFG.embed_dim, num_dense=FULL_CFG.num_dense,
+    bot_mlp=FULL_CFG.bot_mlp, top_mlp=FULL_CFG.top_mlp)
+REDUCED = {"cardinalities": "each field capped at 2M rows for one 16 GB "
+                            "v5e chip (13,116,632 rows, 3.36 GB fp32)"}
+
 _smoke_ds = CriteoSynth(CriteoConfig(num_fields=8, important_fields=4,
                                      num_dense=5))
 SMOKE_CFG = R.DLRMConfig(
@@ -33,4 +49,5 @@ SMOKE_CFG = R.DLRMConfig(
 def arch() -> RecsysArch:
     return RecsysArch(name="dlrm-rm2", model=R.make_dlrm(FULL_CFG),
                       smoke_model=R.make_dlrm(SMOKE_CFG), has_dense=True,
-                      num_dense=13)
+                      num_dense=13, chip_model=R.make_dlrm(CHIP_CFG),
+                      reduced=REDUCED)

@@ -33,6 +33,21 @@ Array = jax.Array
 
 _TIER_SHIFT = 28
 _IDX_MASK = (1 << _TIER_SHIFT) - 1
+# rows quantized per device call: each step of the (eager) row-wise
+# quantizers holds a full-size buffer, so a whole 10M-row tier at once
+# would not fit one chip's HBM
+_QUANT_CHUNK = 1 << 20
+
+
+def _quantize_chunked(fn, rows: np.ndarray, *args, **kw):
+    """``fn(rows) -> (payload, scale)`` over host ``rows`` in chunks of
+    ``_QUANT_CHUNK``; row-wise, so bit-identical to one call."""
+    qs, ss = [], []
+    for i in range(0, rows.shape[0], _QUANT_CHUNK):
+        q, s = fn(jnp.asarray(rows[i:i + _QUANT_CHUNK]), *args, **kw)
+        qs.append(np.asarray(q))
+        ss.append(np.asarray(s))
+    return np.concatenate(qs), np.concatenate(ss)
 
 
 def _scale_f32(s) -> np.ndarray:
@@ -97,15 +112,18 @@ def pack(store: QATStore, cfg: FQuantConfig) -> PackedStore:
 
     # int8 tier: RTN at pack time (serving path; paper Eq. 5-6)
     rows8 = table[idx8] if idx8.size else np.zeros((1, dim), np.float32)
-    q8, s8 = rq.quantize_rowwise(jnp.asarray(rows8), cfg.bits, mode=cfg.mode)
-    q8, s8 = np.asarray(q8), _scale_f32(np.asarray(s8)[:, 0])
+    q8, s8 = _quantize_chunked(rq.quantize_rowwise, rows8, cfg.bits,
+                               mode=cfg.mode)
+    s8 = _scale_f32(s8[:, 0])
 
     rows16 = table[idx16] if idx16.size else np.zeros((1, dim), np.float32)
-    q16, s16 = rq.quantize_half(jnp.asarray(rows16),
-                                strict_fp16=cfg.strict_fp16,
+    def quant16(rows):
+        q, s = rq.quantize_half(rows, strict_fp16=cfg.strict_fp16,
                                 scaled=cfg.scaled_half)
-    q16 = np.asarray(q16.astype(half_dtype))
-    s16 = _scale_f32(np.asarray(s16)[:, 0])
+        return q.astype(half_dtype), s    # cast on device, as XLA rounds
+
+    q16, s16 = _quantize_chunked(quant16, rows16)
+    s16 = _scale_f32(s16[:, 0])
 
     rows32 = table[idx32] if idx32.size else np.zeros((1, dim), np.float32)
 

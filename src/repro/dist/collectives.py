@@ -17,7 +17,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
@@ -58,4 +58,4 @@ def split_kv_decode_attention(mesh, q, k, v, cache_len, scale,
     return shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(None, axis), P(None, axis), P()),
-        out_specs=P(), check_rep=False)(q, k, v, cache_len)
+        out_specs=P(), check_vma=False)(q, k, v, cache_len)

@@ -22,12 +22,12 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.kernels.hashed_gather.autodiff import _hashed_train
 from repro.kernels.hashed_gather.ops import hashed_gather, slot_plan
-from repro.kernels import should_interpret
+from repro.kernels import use_kernel
 
 Array = jax.Array
 
@@ -74,8 +74,7 @@ def sharded_hashed_lookup(hs, cfg, indices: Array, *, mesh,
                           use_pallas: bool | None = None) -> Array:
     """Distributed hashed materialization: int (...,) -> fp32 (..., D),
     replicated.  ``hs`` must be placed by ``shard_hashed``."""
-    if use_pallas is None:
-        use_pallas = not should_interpret()
+    use_pallas = use_kernel(use_pallas)
     idx = jnp.asarray(indices)
     flat = idx.reshape(-1, 1)
     slots, coeff = slot_plan(flat, None, num_chunks=cfg.num_chunks,
@@ -91,7 +90,7 @@ def sharded_hashed_lookup(hs, cfg, indices: Array, *, mesh,
 
     out = shard_map(local, mesh=mesh,
                     in_specs=(P(axis, None), P(axis), P(), P()),
-                    out_specs=P(), check_rep=False)(
+                    out_specs=P(), check_vma=False)(
         hs.pool, hs.pool_scale, slots, coeff)
     return out.reshape(*idx.shape, cfg.dim)
 
@@ -106,8 +105,7 @@ def sharded_hashed_lookup_train(pool: Array, indices: Array, *,
     pool: int (...,) -> fp32 (..., D), replicated.  ``num_slots`` is
     the GLOBAL pool size (the sharded ``pool`` argument may carry
     divisibility padding rows)."""
-    if use_pallas is None:
-        use_pallas = not should_interpret()
+    use_pallas = use_kernel(use_pallas)
     idx = jnp.asarray(indices)
     flat = idx.reshape(-1, 1)
     slots, coeff = slot_plan(flat, None, num_chunks=num_chunks,
@@ -122,7 +120,7 @@ def sharded_hashed_lookup_train(pool: Array, indices: Array, *,
 
     out = shard_map(local, mesh=mesh,
                     in_specs=(P(axis, None), P(), P()),
-                    out_specs=P(), check_rep=False)(pool, slots, coeff)
+                    out_specs=P(), check_vma=False)(pool, slots, coeff)
     return out.reshape(*idx.shape, out.shape[-1])
 
 

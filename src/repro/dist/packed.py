@@ -30,12 +30,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.packed_store import _IDX_MASK, _TIER_SHIFT, PackedStore
 from repro.core.tiers import Tier
-from repro.kernels import should_interpret
+from repro.kernels import use_kernel
 
 Array = jax.Array
 
@@ -205,8 +205,7 @@ def sharded_lookup(packed: PackedStore, indices: Array, *, mesh,
     kernel (K = 1 bags, bit-identical to the jnp path) on TPU, the
     gather/where jnp path where Pallas would be interpreted.
     """
-    if use_pallas is None:
-        use_pallas = not should_interpret()
+    use_pallas = use_kernel(use_pallas)
 
     def local(pk, idx):
         if use_pallas:
@@ -219,7 +218,7 @@ def sharded_lookup(packed: PackedStore, indices: Array, *, mesh,
 
     return shard_map(local, mesh=mesh,
                      in_specs=(packed_pspecs(axis), P()),
-                     out_specs=P(), check_rep=False)(packed, indices)
+                     out_specs=P(), check_vma=False)(packed, indices)
 
 
 def sharded_bag_lookup_rect(packed: PackedStore, indices: Array, *,
@@ -235,8 +234,7 @@ def sharded_bag_lookup_rect(packed: PackedStore, indices: Array, *,
     ``use_pallas=False`` falls back to ``_local_rows`` + in-axis sum
     (the oracle the fused path is tested against).
     """
-    if use_pallas is None:
-        use_pallas = not should_interpret()
+    use_pallas = use_kernel(use_pallas)
 
     def local(pk, idx, w):
         if use_pallas:
@@ -252,11 +250,11 @@ def sharded_bag_lookup_rect(packed: PackedStore, indices: Array, *,
     if weights is None:
         fn = shard_map(lambda pk, idx: local(pk, idx, None), mesh=mesh,
                        in_specs=(pk_specs, P()),
-                       out_specs=P(), check_rep=False)
+                       out_specs=P(), check_vma=False)
         return fn(packed, indices)
     return shard_map(local, mesh=mesh,
                      in_specs=(pk_specs, P(), P()),
-                     out_specs=P(), check_rep=False)(
+                     out_specs=P(), check_vma=False)(
         packed, indices, weights)
 
 
@@ -280,8 +278,7 @@ def sharded_bag_matmul(packed: PackedStore, indices: Array, w: Array, *,
     """
     from repro.kernels.bag_matmul.kernel import bag_matmul_pallas
     from repro.kernels.bag_matmul.ops import _as_w3
-    if use_pallas is None:
-        use_pallas = not should_interpret()
+    use_pallas = use_kernel(use_pallas)
     b, f = indices.shape
     d = packed.payload32.shape[-1]
     w3 = _as_w3(w, f, d).astype(jnp.float32)
@@ -320,11 +317,11 @@ def sharded_bag_matmul(packed: PackedStore, indices: Array, w: Array, *,
     if weights is None:
         fn = shard_map(lambda pk, idx: local(pk, idx, None), mesh=mesh,
                        in_specs=(pk_specs, P()),
-                       out_specs=P(), check_rep=False)
+                       out_specs=P(), check_vma=False)
         return fn(packed, indices)
     return shard_map(local, mesh=mesh,
                      in_specs=(pk_specs, P(), P()),
-                     out_specs=P(), check_rep=False)(
+                     out_specs=P(), check_vma=False)(
         packed, indices, weights)
 
 
@@ -347,8 +344,7 @@ def sharded_lookup_train(table: Array, indices: Array, *, mesh,
     (``FieldSpec.total_rows`` is 512-padded for exactly this).
     """
     from repro.kernels.dequant_bag.autodiff import bag_lookup_train
-    if use_pallas is None:
-        use_pallas = not should_interpret()
+    use_pallas = use_kernel(use_pallas)
 
     def local(tbl, idx):
         v_loc = tbl.shape[0]
@@ -363,7 +359,7 @@ def sharded_lookup_train(table: Array, indices: Array, *, mesh,
 
     out = shard_map(local, mesh=mesh,
                     in_specs=(P(axis, None), P()),
-                    out_specs=P(), check_rep=False)(table, indices)
+                    out_specs=P(), check_vma=False)(table, indices)
     return out.reshape(*indices.shape, table.shape[1])
 
 
@@ -385,9 +381,9 @@ def sharded_bag_lookup(packed: PackedStore, indices: Array,
     if weights is None:
         return shard_map(local, mesh=mesh,
                          in_specs=(pk_specs, P(), P()),
-                         out_specs=P(), check_rep=False)(
+                         out_specs=P(), check_vma=False)(
             packed, indices, segment_ids)
     return shard_map(local, mesh=mesh,
                      in_specs=(pk_specs, P(), P(), P()),
-                     out_specs=P(), check_rep=False)(
+                     out_specs=P(), check_vma=False)(
         packed, indices, segment_ids, weights)

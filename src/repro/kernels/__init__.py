@@ -13,6 +13,9 @@ Interpret mode is auto-detected per process: on TPU the real kernel
 compiles, everywhere else (CPU containers, CI) the Pallas interpreter
 runs the same program.  ``REPRO_PALLAS_INTERPRET=0|1`` force-overrides
 the detection; per-call ``interpret=`` arguments override both.
+
+Every TPU kernel reads its tables through the lane-dense row view of
+``kernels.rows``.
 """
 
 from __future__ import annotations
@@ -41,3 +44,20 @@ def should_interpret(override: bool | None = None) -> bool:
     if override is not None:
         return bool(override)
     return _default_interpret()
+
+
+def use_kernel(use_pallas: bool | None = None,
+               interpret: bool | None = None) -> bool:
+    """Resolve a ``use_pallas=None`` op argument: kernel or jnp oracle.
+
+    An explicit value wins.  On a TPU backend the answer is always the
+    kernel — even with interpretation forced, the oracle never stands
+    in for the device path there.  Elsewhere the oracle runs exactly
+    when the kernel would be interpreted (the interpreter is far
+    slower than the equivalent XLA program on a CPU).
+    """
+    if use_pallas is not None:
+        return bool(use_pallas)
+    if jax.default_backend() == "tpu":
+        return True
+    return not should_interpret(interpret)

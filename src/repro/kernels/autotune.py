@@ -1,10 +1,13 @@
 """Measured autotune cache for Pallas kernel block sizes.
 
-``ops.resolve_block_sizes`` used to stop at an analytic VMEM-budget
-model.  This module adds the measured layer: a timing sweep over
-candidate (B_block, D_block) tilings per
-``(backend, kernel, dtype, B, K, D)`` key, persisted to a versioned
-JSON cache so serving processes never pay the sweep.
+``dequant_bag.ops.resolve_block_b`` (and
+``bag_matmul.ops.resolve_bm_block_sizes``) layer a measured pick over
+their analytic VMEM-budget model.  This module is that layer: a timing
+sweep over candidate tilings per ``(backend, kernel, dtype, B, K, D)``
+key, persisted to a versioned JSON cache so serving processes never
+pay the sweep.  An entry holds the kernel's named blocks
+(``block_b``; ``block_b`` and ``block_h`` for bag_matmul) and the
+measured time.
 
 Contract:
 
@@ -15,8 +18,8 @@ Contract:
     target backend, or the CI ``autotune-smoke`` job on the interpret
     backend — and write through ``store``.
   * Cache location: ``REPRO_AUTOTUNE_CACHE`` env var, else
-    ``results/autotune.json`` relative to the working directory.  An
-    empty env value disables the cache entirely.
+    ``results/autotune.json`` under the repo root.  An empty env value
+    disables the cache entirely.
   * Invalidation: a file whose ``schema`` field is not
     ``autotune_cache/v1`` — or that does not parse, or whose entry is
     malformed — is ignored wholesale (analytic fallback, never an
@@ -36,8 +39,10 @@ from typing import Callable
 
 import jax
 
+from repro import REPO_ROOT
+
 CACHE_SCHEMA = "autotune_cache/v1"
-DEFAULT_CACHE_PATH = os.path.join("results", "autotune.json")
+DEFAULT_CACHE_PATH = str(REPO_ROOT / "results" / "autotune.json")
 
 _ENV = "REPRO_AUTOTUNE_CACHE"
 
@@ -101,31 +106,31 @@ def _entries() -> dict:
 
 
 def lookup_cached(kernel: str, dtype: str, b: int, k: int, d: int,
-                  extra: str = "") -> tuple[int, int] | None:
-    """(block_b, block_d) for the key, or None on miss/malformed entry."""
+                  extra: str = "", fields: tuple[str, ...] = ("block_b",)
+                  ) -> tuple[int, ...] | None:
+    """The entry's ``fields`` (block sizes) for the key, or None on a
+    miss or a malformed entry."""
     e = _entries().get(cache_key(kernel, dtype, b, k, d, extra))
     if not isinstance(e, dict):
         return None
-    bb, bd = e.get("block_b"), e.get("block_d")
-    if (isinstance(bb, int) and isinstance(bd, int)
-            and bb >= 1 and bd >= 1):
-        return bb, bd
+    vals = tuple(e.get(f) for f in fields)
+    if all(isinstance(v, int) and v >= 1 for v in vals):
+        return vals
     return None
 
 
 def store(kernel: str, dtype: str, b: int, k: int, d: int,
-          block_b: int, block_d: int, us: float,
+          blocks: dict[str, int], us: float,
           extra: str = "") -> str | None:
-    """Write one measured entry through to the cache file (atomic
-    replace, other entries preserved).  Returns the path written."""
+    """Write one measured entry (``blocks``: name -> size) through to
+    the cache file (atomic replace, other entries preserved).  Returns
+    the path written."""
     path = cache_path()
     if path is None:
         return None
     entries = dict(_read_entries(path))
     entries[cache_key(kernel, dtype, b, k, d, extra)] = {
-        "block_b": int(block_b), "block_d": int(block_d),
-        "us": float(us),
-    }
+        **{name: int(v) for name, v in blocks.items()}, "us": float(us)}
     parent = os.path.dirname(path)
     if parent:
         os.makedirs(parent, exist_ok=True)
@@ -155,51 +160,46 @@ def time_us(fn: Callable[[], jax.Array], iters: int = 3,
     return best
 
 
-def sweep(run: Callable[[int, int], Callable[[], jax.Array]],
-          candidates: list[tuple[int, int]], iters: int = 3) -> dict:
-    """Time ``run(block_b, block_d)()`` for every candidate tiling.
+def sweep(run: Callable[..., Callable[[], jax.Array]],
+          candidates: list[tuple[int, ...]], iters: int = 3) -> dict:
+    """Time ``run(*blocks)()`` for every candidate tiling.
 
-    Returns ``{"best": (bb, bd), "best_us": t, "sweep": [...]}`` with
-    one ``{"block_b", "block_d", "us"}`` row per candidate.  Candidates
-    that fail to build/launch are recorded with ``us: None`` and
-    excluded from ``best`` (a tiling the backend rejects must never win).
+    Returns ``{"best": blocks, "best_us": t, "sweep": [...]}`` with one
+    ``{"blocks", "us"}`` row per candidate.  Candidates that fail to
+    build/launch are recorded with ``us: None`` and excluded from
+    ``best`` (a tiling the backend rejects must never win).
     """
     rows = []
     best, best_us = None, float("inf")
-    for bb, bd in candidates:
+    for blocks in candidates:
+        blocks = tuple(blocks)
         try:
-            us = time_us(run(bb, bd), iters=iters)
+            us = time_us(run(*blocks), iters=iters)
         except Exception:
-            rows.append({"block_b": bb, "block_d": bd, "us": None})
+            rows.append({"blocks": list(blocks), "us": None})
             continue
-        rows.append({"block_b": bb, "block_d": bd, "us": us})
+        rows.append({"blocks": list(blocks), "us": us})
         if us < best_us:
-            best, best_us = (bb, bd), us
+            best, best_us = blocks, us
     if best is None:
         raise RuntimeError("autotune sweep: every candidate failed")
     return {"best": best, "best_us": best_us, "sweep": rows}
 
 
-def candidate_tilings(b: int, k: int, d: int, itemsize: int = 1
-                      ) -> list[tuple[int, int]]:
-    """Candidate (B_block, D_block) grid around the analytic pick.
+def candidate_block_b(b: int, k: int, d: int, itemsize: int = 1
+                      ) -> list[int]:
+    """Bag-block candidates around the analytic pick, analytic first.
 
-    Always contains the analytic pick itself, so a measured winner is
-    by construction no slower than the analytic model on the swept
-    backend — the invariant ``bench_kernel/v1`` asserts.
+    Every candidate obeys the 8-row tile rule, so each one times the
+    block it names.  The analytic pick is always among them, so a
+    measured winner is by construction no slower than the analytic
+    model on the swept backend — the invariant ``bench_kernel/v1``
+    asserts.
     """
-    from repro.kernels.dequant_bag.ops import resolve_block_sizes
-    ab, ad = resolve_block_sizes(b, k, d, itemsize)
-
-    ds = {ad}
-    divisors = [x for x in range(1, min(d, 512) + 1) if d % x == 0]
-    ds.add(divisors[-1])
-    ds.update(x for x in divisors if x % 128 == 0)
-    if d <= 512:
-        ds.add(d)
-    bs = {ab, max(1, ab // 2), min(b, max(1, ab * 2)), min(b, 8), 1}
-    cands = sorted({(bb, bd) for bb in bs for bd in ds
-                    if 1 <= bb <= b and 1 <= bd})
-    # keep the sweep bounded: analytic pick first, then the rest
-    cands.remove((ab, ad))
-    return [(ab, ad)] + cands[:11]
+    from repro.kernels import rows
+    from repro.kernels.dequant_bag.ops import (_VMEM_SCRATCH_BUDGET,
+                                               _auto_block_b)
+    ab = _auto_block_b(b, k, d, itemsize, _VMEM_SCRATCH_BUDGET)
+    limit = rows.legal_block_b(b)
+    rest = {rows.legal_block_b(x) for x in (ab // 2, ab * 2, 8, limit)}
+    return [ab] + sorted(x for x in rest if x != ab and x <= limit)

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import should_interpret
+from repro.kernels import use_kernel
 from repro.kernels.bag_matmul.kernel import bag_matmul_pallas
 from repro.kernels.bag_matmul.ref import bag_matmul_ref
 from repro.kernels.dequant_bag.autodiff import bag_grad_tpu
@@ -80,8 +80,7 @@ def bag_matmul_train(table: Array, indices: Array, w: Array,
     activations never materialised; gradients w.r.t. ``table`` run the
     Pallas scatter kernel.
     """
-    if use_pallas is None:
-        use_pallas = not should_interpret(interpret)
+    use_pallas = use_kernel(use_pallas, interpret)
     b, k = indices.shape
     d = table.shape[1]
     if weights is None:

@@ -12,14 +12,14 @@ the SHARK serving path.
 Layout (``bag_matmul_pallas``):
 
   grid = (ceil(B / B_block), ceil(H / H_block))
-  indices   (B, K) int32    scalar-prefetched (SMEM)
-  scales    (B_block, K)    VMEM block: gathered row scales
-  weights   (B_block, K)    VMEM block: per-slot weight (0 = skip)
-  payload   (V, D)          HBM (ANY); full rows DMA'd manually
-  w3        (K, D, H_block) VMEM block: per-field first-layer weights
+  indices, scales, weights
+            per-slot 1-D SMEM blocks (``kernels.rows`` slot layout)
+  payload   lane-dense view in HBM (ANY); whole rows DMA'd manually
+  w3        (K, Dp, H_block) VMEM block: per-field first-layer weights
   out       (B_block, H_block) fp32, accumulated in-kernel
-  scratch   rows  (B_block, D) fp32 dequantized field tile
-            land  (nbuf, D)  payload-dtype double-buffered landing ring
+  scratch   rows  (B_block, Dp) fp32 dequantized field tile
+            coeff (B_block, 1)  fp32 per-row scale*weight (scale_after)
+            ring  payload-dtype double-buffered landing ring
             sems  (nbuf,)    one DMA semaphore per ring buffer
 
 Per field k the kernel streams the tile's B_block rows through the
@@ -54,31 +54,27 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import should_interpret
+from repro.kernels import rows, should_interpret
 
 Array = jax.Array
 
 
 def _bag_matmul_kernel(idx_ref, scale_ref, weight_ref, payload_ref,
-                       w_ref, out_ref, rows_ref, land_ref, sems, *,
-                       block_b: int, block_h: int, k: int, nbuf: int,
-                       scale_after: bool):
-    i = pl.program_id(0)
+                       w_ref, out_ref, rows_ref, coeff_ref, ring, sems, *,
+                       block_b: int, k: int, nbuf: int, scale_after: bool,
+                       r: int, g: int, dp: int, prows: int):
     out_ref[...] = jnp.zeros_like(out_ref)
 
     for kk in range(k):
-        def row_dma(b, kk=kk):
-            row = idx_ref[i * block_b + b, kk]
-            buf = b % nbuf
-            return pltpu.make_async_copy(
-                payload_ref.at[pl.ds(row, 1), :],
-                land_ref.at[pl.ds(buf, 1), :],
-                sems.at[buf])
+        def copy(b, kk=kk):
+            return rows.row_copy(payload_ref, ring, sems, b % nbuf,
+                                 idx_ref[b * k + kk], r=r, g=g,
+                                 prows=prows)
 
         def start(b, kk=kk):
-            @pl.when(weight_ref[b, kk] != 0.0)
+            @pl.when(weight_ref[b * k + kk] != 0.0)
             def _():
-                row_dma(b).start()
+                copy(b).start()
 
         def warm(b, carry):
             start(b)
@@ -87,23 +83,28 @@ def _bag_matmul_kernel(idx_ref, scale_ref, weight_ref, payload_ref,
         jax.lax.fori_loop(0, min(nbuf, block_b), warm, 0)
 
         def fill(b, carry, kk=kk):
-            w = weight_ref[b, kk]
+            slot = b * k + kk
+            w = weight_ref[slot]
 
             @pl.when(w != 0.0)
             def _():
-                row_dma(b).wait()
-                row = land_ref[pl.ds(b % nbuf, 1), :].astype(jnp.float32)
+                copy(b).wait()
+                row = rows.read_row(ring, b % nbuf, idx_ref[slot], r=r,
+                                    g=g, dp=dp, prows=prows)
                 if scale_after:
                     rows_ref[pl.ds(b, 1), :] = row
                 else:
-                    rows_ref[pl.ds(b, 1), :] = (row * scale_ref[b, kk]) * w
+                    rows_ref[pl.ds(b, 1), :] = (row * scale_ref[slot]) * w
 
             @pl.when(w == 0.0)
             def _():
                 # dead slots must contribute exact zeros to the matmul
                 # (and never leave uninitialised scratch on the MXU path)
-                rows_ref[pl.ds(b, 1), :] = jnp.zeros(
-                    (1, rows_ref.shape[1]), jnp.float32)
+                rows_ref[pl.ds(b, 1), :] = jnp.zeros((1, dp), jnp.float32)
+
+            if scale_after:
+                coeff_ref[pl.ds(b, 1), :] = jnp.full(
+                    (1, 1), scale_ref[slot] * w, jnp.float32)
 
             @pl.when(b + nbuf < block_b)
             def _():
@@ -115,8 +116,7 @@ def _bag_matmul_kernel(idx_ref, scale_ref, weight_ref, payload_ref,
         prod = jnp.dot(rows_ref[...], w_ref[kk],
                        preferred_element_type=jnp.float32)
         if scale_after:
-            coeff = scale_ref[:, kk] * weight_ref[:, kk]
-            prod = prod * coeff[:, None]
+            prod = prod * coeff_ref[...]
         out_ref[...] += prod
 
 
@@ -130,6 +130,8 @@ def _bag_matmul_call(payload: Array, scales: Array, indices: Array,
     v, d = payload.shape
     b, k = indices.shape
     h = w3.shape[-1]
+    dp, r = rows.row_layout(d)
+    g = rows.dma_group(payload.dtype)
     indices = indices.astype(jnp.int32)
     sg = jnp.take(scales, indices, axis=0).astype(jnp.float32)
     weights = weights.astype(jnp.float32)
@@ -144,37 +146,42 @@ def _bag_matmul_call(payload: Array, scales: Array, indices: Array,
         weights = jnp.pad(weights, ((0, bp - b), (0, 0)))
     nh = -(-h // block_h)
     hp = nh * block_h
-    if hp != h:
-        # non-dividing block_h: pad the weight columns; padded outputs
-        # are sliced off below
-        w3 = jnp.pad(w3, ((0, 0), (0, 0), (0, hp - h)))
+    # pad the weight rows to the row width the kernel lands (zeros: the
+    # padded row lanes are zero too) and the columns to whole H blocks
+    w3 = jnp.pad(w3, ((0, 0), (0, dp - d), (0, hp - h)))
+    (indices, sg, weights), span = rows.flatten_slots(
+        (indices, sg, weights), block_b)
+    phys = rows.lane_dense(payload)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+    out = pl.pallas_call(
+        functools.partial(_bag_matmul_kernel, block_b=block_b, k=k,
+                          nbuf=nbuf, scale_after=scale_after, r=r, g=g,
+                          dp=dp, prows=phys.shape[0]),
         grid=(nb, nh),
-        in_specs=[
-            pl.BlockSpec((block_b, k), lambda i, j, idx: (i, 0)),
-            pl.BlockSpec((block_b, k), lambda i, j, idx: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec((k, d, block_h), lambda i, j, idx: (0, 0, j)),
+        in_specs=[rows.slot_spec(span)] * 3 + [
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((k, dp, block_h), lambda i, j: (0, 0, j)),
         ],
-        out_specs=pl.BlockSpec((block_b, block_h),
-                               lambda i, j, idx: (i, j)),
+        out_specs=pl.BlockSpec((block_b, block_h), lambda i, j: (i, j)),
         scratch_shapes=[
-            pltpu.VMEM((block_b, d), jnp.float32),
-            pltpu.VMEM((nbuf, d), payload.dtype),
+            pltpu.VMEM((block_b, dp), jnp.float32),
+            pltpu.VMEM((block_b, 1), jnp.float32),
+            pltpu.VMEM(rows.ring_shape(nbuf, g, phys.shape[1]),
+                       phys.dtype),
             pltpu.SemaphoreType.DMA((nbuf,)),
         ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_bag_matmul_kernel, block_b=block_b,
-                          block_h=block_h, k=k, nbuf=nbuf,
-                          scale_after=scale_after),
-        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bp, hp), jnp.float32),
         interpret=interpret,
-    )(indices, sg, weights, payload, w3)
+    )(indices, sg, weights, phys, w3)
     return out[:b, :h]
+
+
+def _legal_block_h(block_h: int, h: int) -> int:
+    """The (block_b, block_h) output tile's lane rule: the whole H, or
+    a multiple of 128."""
+    if block_h >= h:
+        return h
+    return min(h, -(-block_h // rows.LANES) * rows.LANES)
 
 
 def bag_matmul_pallas(payload: Array, scales: Array, indices: Array,
@@ -189,7 +196,8 @@ def bag_matmul_pallas(payload: Array, scales: Array, indices: Array,
     One fused kernel call: gather + dequant + per-field matmul
     accumulate; the (B, K, D) fp32 rows exist only in VMEM scratch.
     Block sizes default to ``ops.resolve_bm_block_sizes`` (measured
-    autotune cache under the ``bag_matmul`` key, analytic fallback).
+    autotune cache under the ``bag_matmul`` key, analytic fallback),
+    rounded to the 8 x 128 tile rules.
     """
     b, k = indices.shape
     d = payload.shape[1]
@@ -201,6 +209,8 @@ def bag_matmul_pallas(payload: Array, scales: Array, indices: Array,
     block_b, block_h = resolve_bm_block_sizes(
         b, k, d, h, payload.dtype.itemsize, block_b, block_h,
         dtype=str(payload.dtype))
+    block_b = rows.legal_block_b(block_b)
+    block_h = _legal_block_h(block_h, h)
     if nbuf is None:
         nbuf = resolve_nbuf(block_b)
     nbuf = max(1, min(int(nbuf), block_b))

@@ -22,13 +22,14 @@ sound and saves the (B_block, D) dequant multiply.
 
 from __future__ import annotations
 
+import functools
 import os
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.packed_store import _IDX_MASK, _TIER_SHIFT, PackedStore
-from repro.kernels import should_interpret
+from repro.kernels import rows, use_kernel
 from repro.kernels.bag_matmul.kernel import bag_matmul_pallas
 
 Array = jax.Array
@@ -39,21 +40,46 @@ Array = jax.Array
 _BM_VMEM_BUDGET = 8 << 20
 
 
+@functools.lru_cache(maxsize=512)
+def _auto_block_h(h: int) -> int:
+    """Output-column block: the largest 128-aligned divisor of H that
+    is <= 512; for awkward H > 512 with none, the 128-aligned block
+    <= 512 that wastes the fewest padded columns (ties -> larger);
+    otherwise the largest divisor <= 512."""
+    divisors = [x for x in range(1, min(h, 512) + 1) if h % x == 0]
+    aligned = [x for x in divisors if x % 128 == 0]
+    if aligned:
+        return max(aligned)
+    if h > 512:
+        return min((x for x in range(128, 513, 128)),
+                   key=lambda x: (-(-h // x) * x - h, -x))
+    return max(divisors)
+
+
 def _bm_auto_block_b(b: int, k: int, d: int, block_h: int,
                      itemsize: int) -> int:
+    """Largest power-of-two bag block (>= 8, the tile rule) whose VMEM
+    working set fits ``_BM_VMEM_BUDGET`` (SMEM slot cap as in
+    ``dequant_bag.ops._auto_block_b``)."""
     from repro.kernels.dequant_bag.ops import resolve_nbuf
+    dp, r = rows.row_layout(d)
+    g = rows.SUBLANES * max(1, 4 // itemsize)
     nbuf = resolve_nbuf(max(1, b))
-    fixed = k * d * block_h * 4 + nbuf * d * itemsize  # w block + ring
+    lanes_h = -(-block_h // rows.LANES) * rows.LANES
+    lanes_d = -(-dp // rows.LANES) * rows.LANES
+    fixed = (2 * k * dp * lanes_h * 4              # w block
+             + nbuf * g * r * dp * itemsize)       # landing ring
 
     def fits(bb: int) -> bool:
         working = (fixed
-                   + bb * d * 4          # fp32 rows scratch
-                   + bb * block_h * 4    # fp32 out tile
-                   + 2 * bb * k * 4)     # gathered scales + weights
-        return working <= _BM_VMEM_BUDGET
+                   + bb * lanes_d * 4              # fp32 rows scratch
+                   + 2 * bb * lanes_h * 4)         # fp32 out tile
+        return (working <= _BM_VMEM_BUDGET
+                and bb * k <= rows.MAX_BLOCK_SLOTS)
 
-    block_b = 1
-    while block_b * 2 <= b and fits(block_b * 2):
+    limit = rows.legal_block_b(max(1, b))
+    block_b = rows.SUBLANES
+    while block_b * 2 <= limit and fits(block_b * 2):
         block_b *= 2
     return block_b
 
@@ -65,13 +91,13 @@ def resolve_bm_block_sizes(b: int, k: int, d: int, h: int,
                            dtype: str | None = None) -> tuple[int, int]:
     """(B_block, H_block) for the fused kernel.
 
-    Same layering as ``dequant_bag.ops.resolve_block_sizes``: explicit
+    Same layering as ``dequant_bag.ops.resolve_block_b``: explicit
     argument > ``REPRO_BAGMM_BLOCK_B`` / ``REPRO_BAGMM_BLOCK_H`` env >
     measured autotune-cache hit (kind ``bag_matmul``, keyed on
     (B, K, D) with the output width folded in) > analytic pick.
     """
     from repro.kernels import autotune
-    from repro.kernels.dequant_bag.ops import _auto_block_d, _cache_dtype
+    from repro.kernels.dequant_bag.ops import _cache_dtype
     for name, v in (("block_b", block_b), ("block_h", block_h)):
         if v is not None and v < 1:
             raise ValueError(f"{name} must be >= 1, got {v}")
@@ -81,14 +107,15 @@ def resolve_bm_block_sizes(b: int, k: int, d: int, h: int,
     if block_b is None and block_h is None and not env_b and not env_h:
         cached = autotune.lookup_cached("bag_matmul",
                                         _cache_dtype(itemsize, dtype),
-                                        b, k, d, extra=f"|h={h}")
+                                        b, k, d, extra=f"|h={h}",
+                                        fields=("block_b", "block_h"))
     if block_h is None:
         if env_h:
             block_h = max(1, int(env_h))
         elif cached is not None:
             block_h = cached[1]
         else:
-            block_h = _auto_block_d(h)
+            block_h = _auto_block_h(h)
     if block_b is None:
         if env_b:
             block_b = max(1, int(env_b))
@@ -126,8 +153,7 @@ def packed_bag_matmul(packed: PackedStore, indices: Array, w: Array,
     b, f = indices.shape
     d = packed.dim
     w3 = _as_w3(w, f, d)
-    if use_pallas is None:
-        use_pallas = not should_interpret(interpret)
+    use_pallas = use_kernel(use_pallas, interpret)
     if not use_pallas:
         from repro.core.packed_store import lookup
         rows = lookup(packed, indices)

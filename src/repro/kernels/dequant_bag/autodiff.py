@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import should_interpret
+from repro.kernels import use_kernel
 from repro.kernels.dequant_bag.kernel import (
     bag_grad_pallas,
     bag_grad_pallas_rowgrid,
@@ -49,41 +49,38 @@ def bag_grad_tpu(g: Array, scales: Array | None, indices: Array,
                  weights: Array | None, vocab: int,
                  use_pallas: bool = True,
                  interpret: bool | None = None,
-                 block_b: int | None = None,
-                 block_d: int | None = None) -> Array:
+                 block_b: int | None = None) -> Array:
     """Scatter-add bag transpose with the forward ops' dispatch shape:
     the tiled Pallas kernel, or the jnp ``segment_sum`` oracle."""
     if not use_pallas:
         return bag_grad_ref(g, scales, indices, weights, vocab)
     return bag_grad_pallas(g, scales, indices, weights, vocab,
-                           interpret=interpret, block_b=block_b,
-                           block_d=block_d)
+                           interpret=interpret, block_b=block_b)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _bag_train(table: Array, indices: Array, weights: Array,
                use_pallas: bool, interpret: bool | None,
-               block_b: int | None, block_d: int | None) -> Array:
+               block_b: int | None) -> Array:
     ones = jnp.ones((table.shape[0],), jnp.float32)
     if not use_pallas:
         return dequant_bag_ref(table, ones, indices, weights)
     return dequant_bag_pallas(table, ones, indices, weights,
-                              interpret=interpret,
-                              block_b=block_b, block_d=block_d)
+                              interpret=interpret, block_b=block_b)
 
 
 def _bag_train_fwd(table, indices, weights, use_pallas, interpret,
-                   block_b, block_d):
+                   block_b):
     out = _bag_train(table, indices, weights, use_pallas, interpret,
-                     block_b, block_d)
+                     block_b)
     return out, (table, indices, weights)
 
 
-def _bag_train_bwd(use_pallas, interpret, block_b, block_d, res, g):
+def _bag_train_bwd(use_pallas, interpret, block_b, res, g):
     table, indices, weights = res
     dtable = bag_grad_tpu(g, None, indices, weights, table.shape[0],
                           use_pallas=use_pallas, interpret=interpret,
-                          block_b=block_b, block_d=block_d)
+                          block_b=block_b)
     rows = jnp.take(table, indices, axis=0).astype(jnp.float32)
     dweights = jnp.einsum("bkd,bd->bk", rows, g.astype(jnp.float32))
     didx = np.zeros(indices.shape, dtype=jax.dtypes.float0)
@@ -97,8 +94,7 @@ def bag_lookup_train(table: Array, indices: Array,
                      weights: Array | None = None, *,
                      use_pallas: bool | None = None,
                      interpret: bool | None = None,
-                     block_b: int | None = None,
-                     block_d: int | None = None) -> Array:
+                     block_b: int | None = None) -> Array:
     """Differentiable embedding bag through the serving kernels.
 
     table (V, D) fp32, indices (B, K) -> (B, D) fp32 bag sums;
@@ -106,13 +102,12 @@ def bag_lookup_train(table: Array, indices: Array,
     both directions).  Gradients w.r.t. ``table`` run the scatter-add
     Pallas kernel; w.r.t. ``weights`` the jnp row-dot path.
     """
-    if use_pallas is None:
-        use_pallas = not should_interpret(interpret)
+    use_pallas = use_kernel(use_pallas, interpret)
     b, k = indices.shape
     if weights is None:
         weights = jnp.ones((b, k), jnp.float32)
     return _bag_train(table, indices, weights, bool(use_pallas),
-                      interpret, block_b, block_d)
+                      interpret, block_b)
 
 
 def lookup_train(table: Array, indices: Array, *,

@@ -9,31 +9,25 @@ intermediate never exists.
 
 Tiled layout (``dequant_bag_pallas``):
 
-  grid = (ceil(B / B_block), D / D_block)
-  indices   (B, K) int32   scalar-prefetched (SMEM): row addressing
-  scales    (B_block, K)   VMEM block: per-slot gathered row scales
-  weights   (B_block, K)   VMEM block: per-slot weight (0 = padded slot)
-  payload   (V, D)         stays in HBM (ANY); rows DMA'd manually
-  out       (B_block, D_block) VMEM, accumulated in-kernel
-  scratch   (B_block*K, D_block) payload-dtype row landing buffer
-            + one DMA semaphore per slot
+  grid = (ceil(B / B_block),)          B_block a multiple of 8
+  indices, scales, weights
+            per-slot 1-D SMEM blocks of B_block*K slots (padded to
+            1024 words; ``kernels.rows`` slot layout)
+  payload   lane-dense (P, r*Dp) view in HBM (ANY); rows DMA'd manually
+  out       (B_block, Dp) VMEM, accumulated in-kernel
+  scratch   ``nbuf``-deep landing ring of whole physical rows (fp32) or
+            packed tiles (int8 / bf16), one DMA semaphore per buffer
 
-Each grid step streams its (B_block, K) slots through a
-**double-buffered landing ring**: an ``nbuf``-deep scratch of (1,
-D_block) row buffers with one DMA semaphore each.  The first ``nbuf``
-live slots' copies are issued up front; draining slot *i* then waits
-its buffer, accumulates ``(row * scale) * weight`` into the output
-tile, and immediately starts slot *i+nbuf*'s copy into the freed
-buffer — so row DMA latency hides behind the VPU dequant math instead
-of serializing with it, with up to ``nbuf`` transfers in flight.
-Zero-weight (padded / other-tier) slots skip both the start and the
-wait.  Ring depth defaults to ``ops.resolve_nbuf`` (env
-``REPRO_DEQUANT_NBUF``); the ring replaces the old (B_block*K,
-D_block) all-slots landing buffer, shrinking scratch VMEM from
-O(B_block*K) rows to O(nbuf) and freeing budget for larger output
-tiles (see ``ops._auto_block_b``).  Blocking over D keeps the
-footprint bounded for large dims (a (1, D) tile never has to fit a
-whole row).
+Each grid step streams its B_block*K slots through the ring: the first
+``nbuf`` live slots' copies are issued up front; draining slot *i* then
+waits its buffer, picks the logical row out of the landed rows
+(``rows.read_row``), accumulates ``(row * scale) * weight`` into the
+output tile, and immediately starts slot *i+nbuf*'s copy into the freed
+buffer — so row DMA latency hides behind the VPU dequant math, with up
+to ``nbuf`` transfers in flight.  Zero-weight (padded / other-tier)
+slots skip both the start and the wait.  Ring depth defaults to
+``ops.resolve_nbuf`` (env ``REPRO_DEQUANT_NBUF``).  Rows move whole:
+a TPU row DMA must span the lane dimension (see ``kernels.rows``).
 
 Accumulation is sequential in k per bag, so results are bit-identical
 to the (B, K)-grid kernel (kept as ``dequant_bag_pallas_rowgrid``) and
@@ -44,9 +38,11 @@ order, where the original grid kernel computed ``row * (scale *
 weight)`` — up to 1 ulp apart per slot — so that rowgrid-vs-tiled
 bit-equality isolates the *tiling* change.
 
-On the 819 GB/s HBM of v5e the traffic is roofline-optimal: exactly the
-bytes of the touched rows, ~4x fewer than the fp32 path — the
-kernel-level realisation of the paper's +30% QPS.
+HBM traffic per live slot: one 128-lane physical row for fp32 tables,
+one packed (8, 128)-word tile for int8 / bf16 (the smallest DMA the
+compiler accepts at a data-dependent row), plus one relayout copy of
+the table per call into the lane-dense view.  Time on the chip: not
+measured yet (PERF.md).
 """
 
 from __future__ import annotations
@@ -58,32 +54,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import should_interpret
+from repro.kernels import rows, should_interpret
 
 Array = jax.Array
 
 
 def _tiled_kernel(idx_ref, scale_ref, weight_ref, payload_ref, out_ref,
-                  rows_ref, sems, *, block_b: int, block_d: int, k: int,
-                  nbuf: int):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    d0 = j * block_d
+                  ring, sems, *, block_b: int, k: int, nbuf: int, r: int,
+                  g: int, dp: int, prows: int):
     nslots = block_b * k
 
-    def row_dma(slot):
-        b, kk = slot // k, slot % k
-        row = idx_ref[i * block_b + b, kk]
-        buf = slot % nbuf
-        return pltpu.make_async_copy(
-            payload_ref.at[pl.ds(row, 1), pl.ds(d0, block_d)],
-            rows_ref.at[pl.ds(buf, 1), :],
-            sems.at[buf])
+    def copy(slot):
+        return rows.row_copy(payload_ref, ring, sems, slot % nbuf,
+                             idx_ref[slot], r=r, g=g, prows=prows)
 
     def start(slot):
-        @pl.when(weight_ref[slot // k, slot % k] != 0.0)
+        @pl.when(weight_ref[slot] != 0.0)
         def _():
-            row_dma(slot).start()
+            copy(slot).start()
 
     # prime the ring: the first nbuf slots' copies go in flight now
     def warm(slot, carry):
@@ -94,15 +82,15 @@ def _tiled_kernel(idx_ref, scale_ref, weight_ref, payload_ref, out_ref,
     out_ref[...] = jnp.zeros_like(out_ref)
 
     def drain(slot, carry):
-        b, kk = slot // k, slot % k
-        w = weight_ref[b, kk]
+        w = weight_ref[slot]
 
         @pl.when(w != 0.0)
         def _():
-            row_dma(slot).wait()
-            buf = slot % nbuf
-            row = rows_ref[pl.ds(buf, 1), :].astype(jnp.float32)
-            out_ref[pl.ds(b, 1), :] += (row * scale_ref[b, kk]) * w
+            copy(slot).wait()
+            row = rows.read_row(ring, slot % nbuf, idx_ref[slot], r=r, g=g,
+                                dp=dp, prows=prows)
+            b = slot // k
+            out_ref[pl.ds(b, 1), :] += (row * scale_ref[slot]) * w
 
         # refill: slot+nbuf reuses this buffer, which is free exactly
         # now — its DMA (if any) was waited above.  Issued even when
@@ -117,13 +105,14 @@ def _tiled_kernel(idx_ref, scale_ref, weight_ref, payload_ref, out_ref,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("block_b", "block_d", "nbuf",
-                                    "interpret"))
+                   static_argnames=("block_b", "nbuf", "interpret"))
 def _tiled_call(payload: Array, scales: Array, indices: Array,
-                weights: Array, *, block_b: int, block_d: int,
-                nbuf: int, interpret: bool) -> Array:
+                weights: Array, *, block_b: int, nbuf: int,
+                interpret: bool) -> Array:
     v, d = payload.shape
     b, k = indices.shape
+    dp, r = rows.row_layout(d)
+    g = rows.dma_group(payload.dtype)
     indices = indices.astype(jnp.int32)
     sg = jnp.take(scales, indices, axis=0).astype(jnp.float32)
     weights = weights.astype(jnp.float32)
@@ -136,36 +125,25 @@ def _tiled_call(payload: Array, scales: Array, indices: Array,
         indices = jnp.pad(indices, ((0, bp - b), (0, 0)))
         sg = jnp.pad(sg, ((0, bp - b), (0, 0)))
         weights = jnp.pad(weights, ((0, bp - b), (0, 0)))
-    nd = -(-d // block_d)
-    dp = nd * block_d
-    if dp != d:
-        # correctness path for explicit non-dividing block_d: pad the
-        # payload columns once (the block picker always chooses a
-        # divisor of D, so the hot path never copies)
-        payload = jnp.pad(payload, ((0, 0), (0, dp - d)))
+    (indices, sg, weights), span = rows.flatten_slots(
+        (indices, sg, weights), block_b)
+    phys = rows.lane_dense(payload)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nb, nd),
-        in_specs=[
-            pl.BlockSpec((block_b, k), lambda i, j, idx: (i, 0)),
-            pl.BlockSpec((block_b, k), lambda i, j, idx: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=pl.BlockSpec((block_b, block_d),
-                               lambda i, j, idx: (i, j)),
+    out = pl.pallas_call(
+        functools.partial(_tiled_kernel, block_b=block_b, k=k, nbuf=nbuf,
+                          r=r, g=g, dp=dp, prows=phys.shape[0]),
+        grid=(nb,),
+        in_specs=[rows.slot_spec(span)] * 3
+        + [pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((block_b, dp), lambda i: (i, 0)),
         scratch_shapes=[
-            pltpu.VMEM((nbuf, block_d), payload.dtype),
+            pltpu.VMEM(rows.ring_shape(nbuf, g, phys.shape[1]),
+                       phys.dtype),
             pltpu.SemaphoreType.DMA((nbuf,)),
         ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_tiled_kernel, block_b=block_b,
-                          block_d=block_d, k=k, nbuf=nbuf),
-        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bp, dp), jnp.float32),
         interpret=interpret,
-    )(indices, sg, weights, payload)
+    )(indices, sg, weights, phys)
     return out[:b, :d]
 
 
@@ -173,32 +151,29 @@ def dequant_bag_pallas(payload: Array, scales: Array, indices: Array,
                        weights: Array | None = None,
                        interpret: bool | None = None, *,
                        block_b: int | None = None,
-                       block_d: int | None = None,
                        nbuf: int | None = None) -> Array:
     """payload (V, D), scales (V,), indices (B, K) -> (B, D) fp32 bags.
 
-    Tiled (B_block, D_block) kernel with an ``nbuf``-deep
-    double-buffered row-DMA landing ring; block sizes default to
-    ``ops.pick_block_sizes`` (measured autotune cache over the analytic
-    model), ``nbuf`` to ``ops.resolve_nbuf``.  ``interpret`` defaults
-    to backend auto-detection (``kernels.should_interpret``).
+    Bag-blocked kernel with an ``nbuf``-deep row-DMA landing ring over
+    the lane-dense table view (``kernels.rows``).  ``block_b`` resolves
+    through ``ops.resolve_block_b`` (measured autotune cache over the
+    analytic model), rounded up to the 8-row tile rule; rows always
+    move whole, so B is the only tiled dimension.  ``nbuf`` defaults to ``ops.resolve_nbuf``; ``interpret`` to backend
+    auto-detection (``kernels.should_interpret``).
     """
     b, k = indices.shape
     d = payload.shape[1]
     if weights is None:
         weights = jnp.ones((b, k), jnp.float32)
-    from repro.kernels.dequant_bag.ops import (resolve_block_sizes,
+    from repro.kernels.dequant_bag.ops import (resolve_block_b,
                                                resolve_nbuf)
-    block_b, block_d = resolve_block_sizes(b, k, d,
-                                           payload.dtype.itemsize,
-                                           block_b, block_d,
-                                           kind="dequant_bag",
-                                           dtype=str(payload.dtype))
+    block_b = resolve_block_b(b, k, d, payload.dtype.itemsize, block_b,
+                              kind="dequant_bag", dtype=str(payload.dtype))
     if nbuf is None:
         nbuf = resolve_nbuf(block_b * k)
     nbuf = max(1, min(int(nbuf), block_b * k))
     return _tiled_call(payload, scales, indices, weights,
-                       block_b=block_b, block_d=block_d, nbuf=nbuf,
+                       block_b=block_b, nbuf=nbuf,
                        interpret=should_interpret(interpret))
 
 
@@ -267,17 +242,19 @@ def dequant_bag_pallas_rowgrid(payload: Array, scales: Array,
 #
 # The transpose of the forward gather: dtable[i] += coeff[b,k] * g[b]
 # for every slot with idx[b,k] == i, where coeff = weight * scale.  The
-# (V, D) gradient lives in HBM (ANY memory space, aliased onto a zeros
-# input so accumulation is read-modify-write); each slot's row slice is
-# DMA'd into a one-row VMEM scratch, accumulated, and DMA'd back.  TPU
-# grid steps run sequentially, so the RMW is race-free; slots are
-# drained in (b, k) lexicographic order — identical in the tiled and
-# rowgrid layouts, which makes the two kernels bit-equal and the result
-# invariant to (block_b, block_d).
+# gradient lives in HBM in the lane-dense view (ANY memory space,
+# aliased onto a zeros input so accumulation is read-modify-write);
+# each slot's whole physical row is DMA'd into VMEM, the cotangent is
+# rolled onto the logical row's lanes and accumulated, and the row is
+# DMA'd back.  TPU grid steps run sequentially, so the RMW is
+# race-free; slots are drained in (b, k) lexicographic order —
+# identical in the tiled and rowgrid layouts, which makes the two
+# kernels bit-equal and the result invariant to block_b.
 #
 # Unlike the forward, row DMAs here cannot be batch-issued arbitrarily
 # far ahead of the waits: two slots of one tile may address the SAME
-# row, and the second read must observe the first write.  What CAN
+# physical row (the same logical row, or two logical rows sharing it),
+# and the second read must observe the first write.  What CAN
 # overlap — and does, via a two-buffer ring — is slot i+1's row *load*
 # with slot i's row *store*, whenever the two slots address different
 # rows: the next read races only the current write, and the row-index
@@ -285,50 +262,40 @@ def dequant_bag_pallas_rowgrid(payload: Array, scales: Array,
 # (and the slot after a dead slot) fall back to load-after-store.
 # Accumulation order stays (b, k) lexicographic either way — identical
 # in the tiled and rowgrid layouts, which keeps the two kernels
-# bit-equal and the result invariant to (block_b, block_d).  The
-# D-blocked grid keeps the write-combining traffic at exactly the
-# touched-row bytes per column stripe — the roofline-relevant quantity
-# for the QAT backward.
+# bit-equal and the result invariant to block_b.
 
 
-def _bag_grad_tiled_kernel(idx_ref, g_ref, coeff_ref, zeros_ref, out_ref,
-                           rows_ref, sems, *, block_b: int, block_d: int,
-                           k: int):
+def _bag_grad_tiled_kernel(idx_ref, coeff_ref, g_ref, zeros_ref, out_ref,
+                           ring, sems, *, block_b: int, k: int, r: int,
+                           dp: int, prows: int):
     del zeros_ref
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    d0 = j * block_d
     nslots = block_b * k
 
-    def row_of(slot):
-        s = jnp.minimum(slot, nslots - 1)  # clamp for slot == nslots
-        return idx_ref[i * block_b + s // k, s % k]
+    def clamp(slot):
+        return jnp.minimum(slot, nslots - 1)  # slot == nslots
 
-    def coeff_of(slot):
-        s = jnp.minimum(slot, nslots - 1)
-        return coeff_ref[s // k, s % k]
+    def phys_of(slot):
+        return rows.phys_row(idx_ref[clamp(slot)], r=r, prows=prows)
 
     def load_dma(slot):
-        buf = slot % 2
-        src = out_ref.at[pl.ds(row_of(slot), 1), pl.ds(d0, block_d)]
-        return pltpu.make_async_copy(src, rows_ref.at[pl.ds(buf, 1), :],
-                                     sems.at[buf])
+        return rows.row_copy(out_ref, ring, sems, slot % 2,
+                             idx_ref[clamp(slot)], r=r, g=1, prows=prows)
 
     def store_dma(slot):
         buf = slot % 2
-        dst = out_ref.at[pl.ds(row_of(slot), 1), pl.ds(d0, block_d)]
-        return pltpu.make_async_copy(rows_ref.at[pl.ds(buf, 1), :], dst,
+        return pltpu.make_async_copy(ring.at[pl.ds(buf, 1), :],
+                                     out_ref.at[pl.ds(phys_of(slot), 1), :],
                                      sems.at[buf])
 
     def scatter(slot, prefetched):
-        b, kk = slot // k, slot % k
-        c = coeff_ref[b, kk]
+        c = coeff_ref[slot]
         nxt = slot + 1
         # the next slot's load may overlap this slot's store only when
-        # it is live, in range, and addresses a DIFFERENT row (a
-        # same-row read must observe this write)
-        can_prefetch = ((nxt < nslots) & (coeff_of(nxt) != 0.0)
-                        & (row_of(nxt) != row_of(slot)))
+        # it is live, in range, and addresses a DIFFERENT physical row
+        # (a same-row read must observe this write; with r > 1 two
+        # logical rows can share one physical row)
+        can_prefetch = ((nxt < nslots) & (coeff_ref[clamp(nxt)] != 0.0)
+                        & (phys_of(nxt) != phys_of(slot)))
 
         @pl.when((c != 0.0) & (prefetched == 0))
         def _():
@@ -337,7 +304,10 @@ def _bag_grad_tiled_kernel(idx_ref, g_ref, coeff_ref, zeros_ref, out_ref,
         @pl.when(c != 0.0)
         def _():
             load_dma(slot).wait()
-            rows_ref[pl.ds(slot % 2, 1), :] += c * g_ref[pl.ds(b, 1), :]
+            buf = slot % 2
+            gb = g_ref[pl.ds(slot // k, 1), :]
+            ring[pl.ds(buf, 1), :] += c * rows.place_row(
+                gb, idx_ref[slot], r=r, dp=dp, prows=prows)
             store_dma(slot).start()
 
             @pl.when(can_prefetch)
@@ -354,16 +324,20 @@ def _bag_grad_tiled_kernel(idx_ref, g_ref, coeff_ref, zeros_ref, out_ref,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("vocab", "block_b", "block_d",
-                                    "interpret"))
+                   static_argnames=("vocab", "block_b", "interpret"))
 def _bag_grad_tiled_call(g: Array, coeff: Array, indices: Array, *,
-                         vocab: int, block_b: int, block_d: int,
+                         vocab: int, block_b: int,
                          interpret: bool) -> Array:
     b, k = indices.shape
     d = g.shape[1]
+    dp, r = rows.row_layout(d)
+    width = r * dp
     indices = indices.astype(jnp.int32)
-    g = g.astype(jnp.float32)
     coeff = coeff.astype(jnp.float32)
+    # cotangent rows padded to one physical row's width: lanes [0, d)
+    # hold the value, the rest are zero so rolling it into place adds
+    # nothing to the neighbouring logical rows
+    g = jnp.pad(g.astype(jnp.float32), ((0, 0), (0, width - d)))
 
     nb = -(-b // block_b)
     bp = nb * block_b
@@ -372,54 +346,43 @@ def _bag_grad_tiled_call(g: Array, coeff: Array, indices: Array, *,
         indices = jnp.pad(indices, ((0, bp - b), (0, 0)))
         g = jnp.pad(g, ((0, bp - b), (0, 0)))
         coeff = jnp.pad(coeff, ((0, bp - b), (0, 0)))
-    nd = -(-d // block_d)
-    dp = nd * block_d
-    if dp != d:
-        # non-dividing block_d: zero-pad the cotangent columns; the pad
-        # columns scatter zeros and are sliced off the result
-        g = jnp.pad(g, ((0, 0), (0, dp - d)))
+    (indices, coeff), span = rows.flatten_slots((indices, coeff), block_b)
+    zeros = rows.lane_dense(jnp.zeros((vocab, d), jnp.float32))
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nb, nd),
-        in_specs=[
-            pl.BlockSpec((block_b, block_d), lambda i, j, idx: (i, j)),
-            pl.BlockSpec((block_b, k), lambda i, j, idx: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+    out = pl.pallas_call(
+        functools.partial(_bag_grad_tiled_kernel, block_b=block_b, k=k,
+                          r=r, dp=dp, prows=zeros.shape[0]),
+        grid=(nb,),
+        in_specs=[rows.slot_spec(span)] * 2 + [
+            pl.BlockSpec((block_b, width), lambda i: (i, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
-            pltpu.VMEM((2, block_d), jnp.float32),
+            pltpu.VMEM((2, width), jnp.float32),
             pltpu.SemaphoreType.DMA((2,)),
         ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_bag_grad_tiled_kernel, block_b=block_b,
-                          block_d=block_d, k=k),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((vocab, dp), jnp.float32),
-        # operand 3 = the zeros buffer (after scalar-prefetch indices,
-        # g and coeff); aliasing it onto the output turns the kernel
-        # into an in-place accumulate
+        out_shape=jax.ShapeDtypeStruct(zeros.shape, jnp.float32),
+        # operand 3 = the zeros buffer; aliasing it onto the output
+        # turns the kernel into an in-place accumulate
         input_output_aliases={3: 0},
         interpret=interpret,
-    )(indices, g, coeff, jnp.zeros((vocab, dp), jnp.float32))
-    return out[:, :d]
+    )(indices, coeff, g, zeros)
+    return rows.from_lane_dense(out, vocab, d)
 
 
 def bag_grad_pallas(g: Array, scales: Array | None, indices: Array,
                     weights: Array | None, vocab: int,
                     interpret: bool | None = None, *,
-                    block_b: int | None = None,
-                    block_d: int | None = None) -> Array:
+                    block_b: int | None = None) -> Array:
     """g (B, D) fp32, indices (B, K) -> dtable (vocab, D) fp32.
 
-    The scatter-add transpose of ``dequant_bag_pallas``; tiled
-    (B_block, D_block) grid with K looped in-kernel, the RMW pipelined
-    two slots deep with a same-row conflict guard (see the kernel
-    comment).  Block sizes default to the shared picker under the
-    ``bag_grad`` autotune-cache key (the scratch here is two fp32
-    rows, strictly smaller than the forward's landing ring).
+    The scatter-add transpose of ``dequant_bag_pallas``: bag-blocked
+    grid with K looped in-kernel, each slot a read-modify-write of its
+    whole lane-dense physical row, pipelined two slots deep with a
+    same-row conflict guard (see the kernel comment).  ``block_b``
+    resolves through the shared picker under the ``bag_grad``
+    autotune-cache key.
     """
     b, k = indices.shape
     d = g.shape[1]
@@ -427,12 +390,11 @@ def bag_grad_pallas(g: Array, scales: Array | None, indices: Array,
         else weights.astype(jnp.float32)
     if scales is not None:
         coeff = coeff * jnp.take(scales, indices, axis=0)
-    from repro.kernels.dequant_bag.ops import resolve_block_sizes
-    block_b, block_d = resolve_block_sizes(b, k, d, 4, block_b, block_d,
-                                           kind="bag_grad",
-                                           dtype="float32")
+    from repro.kernels.dequant_bag.ops import resolve_block_b
+    block_b = resolve_block_b(b, k, d, 4, block_b, kind="bag_grad",
+                              dtype="float32")
     return _bag_grad_tiled_call(g, coeff, indices, vocab=vocab,
-                                block_b=block_b, block_d=block_d,
+                                block_b=block_b,
                                 interpret=should_interpret(interpret))
 
 
@@ -467,9 +429,9 @@ def _bag_grad_rowgrid_call(g: Array, coeff: Array, indices: Array, *,
         in_specs=[
             pl.BlockSpec((1, d), lambda i, j, idx: (i, 0)),
             pl.BlockSpec((1, 1), lambda i, j, idx: (i, j)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
             pltpu.VMEM((1, d), jnp.float32),
             pltpu.SemaphoreType.DMA,

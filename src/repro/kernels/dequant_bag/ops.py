@@ -8,10 +8,10 @@ weights, which the tiled kernel skips without issuing their row DMAs.
 serving gather with no (B*K, D) fp32 intermediate, bit-identical to
 ``packed_store.lookup``.
 
-Block sizes come from ``pick_block_sizes``, which layers four sources
-per dimension (highest wins): explicit call argument, the
-``REPRO_DEQUANT_BLOCK_B`` / ``REPRO_DEQUANT_BLOCK_D`` env overrides,
-a **measured autotune cache** entry (``kernels.autotune`` — a timing
+The bag block comes from ``resolve_block_b``, which layers four
+sources (highest wins): explicit call argument, the
+``REPRO_DEQUANT_BLOCK_B`` env override, a **measured autotune cache**
+entry (``kernels.autotune`` — a timing
 sweep persisted per backend/kernel/dtype/shape, seeded out-of-band by
 ``benchmarks.kernels --seed-cache``), and finally the analytic
 VMEM-budget model.  A cold cache miss therefore costs nothing: the
@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.packed_store import _IDX_MASK, _TIER_SHIFT, PackedStore
-from repro.kernels import should_interpret
+from repro.kernels import rows, use_kernel
 from repro.kernels.dequant_bag.kernel import dequant_bag_pallas
 from repro.kernels.dequant_bag.ref import dequant_bag_ref
 
@@ -54,36 +54,28 @@ def resolve_nbuf(nslots: int) -> int:
 
 
 @functools.lru_cache(maxsize=512)
-def _auto_block_d(d: int) -> int:
-    divisors = [x for x in range(1, min(d, 512) + 1) if d % x == 0]
-    aligned = [x for x in divisors if x % 128 == 0]
-    if aligned:
-        return max(aligned)
-    if d > 512:
-        # awkward dims (prime/odd > 512): no 128-aligned divisor
-        # exists, and the largest plain divisor can degenerate to 1 —
-        # serializing the whole D axis.  The tiled kernels handle
-        # non-dividing blocks via the column-padding edge path, so pick
-        # the 128-aligned block <= 512 that minimises edge-tile waste
-        # (ties -> larger block, fewer grid steps).
-        return min((x for x in range(128, 513, 128)),
-                   key=lambda x: (-(-d // x) * x - d, -x))
-    return max(divisors)
-
-
-@functools.lru_cache(maxsize=512)
-def _auto_block_b(b: int, k: int, block_d: int, itemsize: int,
+def _auto_block_b(b: int, k: int, d: int, itemsize: int,
                   vmem_budget: int) -> int:
+    """Largest power-of-two bag block (>= 8, the output tile's sublane
+    rule) whose fp32 output tile, double-buffered, plus the landing
+    ring of whole lane-dense rows of a width-``d`` table fits
+    ``vmem_budget``, with at most ``rows.MAX_BLOCK_SLOTS`` slots per
+    block (SMEM)."""
+    dp, r = rows.row_layout(d)
+    width = r * dp
+    lanes = -(-dp // rows.LANES) * rows.LANES  # VMEM pads to full lanes
+    g = rows.SUBLANES * max(1, 4 // itemsize)
     nbuf = resolve_nbuf(max(1, b) * k)
 
     def fits(bb: int) -> bool:
-        working = (bb * block_d * 4          # fp32 output tile
-                   + nbuf * block_d * itemsize  # row landing ring
-                   + 2 * bb * k * 4)         # gathered scales + weights
-        return working <= vmem_budget
+        working = (2 * bb * lanes * 4                # fp32 output tile
+                   + nbuf * g * width * itemsize)    # row landing ring
+        return (working <= vmem_budget
+                and bb * k <= rows.MAX_BLOCK_SLOTS)
 
-    block_b = 1
-    while block_b * 2 <= b and fits(block_b * 2):
+    limit = rows.legal_block_b(max(1, b))
+    block_b = rows.SUBLANES
+    while block_b * 2 <= limit and fits(block_b * 2):
         block_b *= 2
     return block_b
 
@@ -95,82 +87,58 @@ def _cache_dtype(itemsize: int, dtype: str | None) -> str:
         itemsize, f"itemsize{itemsize}")
 
 
-def resolve_block_sizes(b: int, k: int, d: int, itemsize: int = 1,
-                        block_b: int | None = None,
-                        block_d: int | None = None,
-                        vmem_budget: int = _VMEM_SCRATCH_BUDGET,
-                        kind: str = "dequant_bag",
-                        dtype: str | None = None) -> tuple[int, int]:
-    """Layer (B_block, D_block) overrides over cache and analytic picks.
+def resolve_block_b(b: int, k: int, d: int, itemsize: int = 1,
+                    block_b: int | None = None,
+                    vmem_budget: int = _VMEM_SCRATCH_BUDGET,
+                    kind: str = "dequant_bag",
+                    dtype: str | None = None) -> int:
+    """The bag block (B_block) the tiled kernels run with.
 
-    Precedence per dimension: explicit argument, then
-    ``REPRO_DEQUANT_BLOCK_B`` / ``REPRO_DEQUANT_BLOCK_D`` (read per
-    call, so changing them mid-process takes effect), then a measured
+    Precedence: explicit argument, then ``REPRO_DEQUANT_BLOCK_B`` (read
+    per call, so changing it mid-process takes effect), then a measured
     autotune-cache hit for ``(backend, kind, dtype, b, k, d)``
     (``kernels.autotune``; read-only — a miss never triggers a sweep),
-    then the analytic pick.  An overridden D_block — from any source —
-    re-sizes an unspecified B_block against the *overridden* value, so
-    the VMEM budget holds whichever dimension was pinned.
+    then the analytic pick.  Whatever the source, the result is rounded
+    up to the 8-row tile rule (``rows.legal_block_b``): the value
+    returned is the block that runs.  Rows always move whole, so B is
+    the only tiled dimension.
     """
-    for name, v in (("block_b", block_b), ("block_d", block_d)):
-        if v is not None and v < 1:
-            raise ValueError(f"{name} must be >= 1, got {v}")
-    env_b = os.environ.get("REPRO_DEQUANT_BLOCK_B")
-    env_d = os.environ.get("REPRO_DEQUANT_BLOCK_D")
-    cached = None
-    if block_b is None and block_d is None and not env_b and not env_d:
-        # a cache entry is a jointly-tuned pair: it only applies when
-        # neither dimension is pinned by an argument or env override
-        from repro.kernels import autotune
-        cached = autotune.lookup_cached(kind,
-                                        _cache_dtype(itemsize, dtype),
-                                        b, k, d)
-    if block_d is None:
-        if env_d:
-            block_d = max(1, int(env_d))
-        elif cached is not None:
-            block_d = cached[1]
-        else:
-            block_d = _auto_block_d(d)
+    if block_b is not None and block_b < 1:
+        raise ValueError(f"block_b must be >= 1, got {block_b}")
     if block_b is None:
+        env_b = os.environ.get("REPRO_DEQUANT_BLOCK_B")
         if env_b:
             block_b = max(1, int(env_b))
-        elif cached is not None:
-            block_b = cached[0]
         else:
-            block_b = _auto_block_b(b, k, int(block_d), itemsize,
-                                    vmem_budget)
-    return int(block_b), int(block_d)
+            from repro.kernels import autotune
+            cached = autotune.lookup_cached(
+                kind, _cache_dtype(itemsize, dtype), b, k, d)
+            block_b = (cached[0] if cached is not None
+                       else _auto_block_b(b, k, d, itemsize, vmem_budget))
+    return rows.legal_block_b(block_b)
 
 
-def pick_block_sizes(b: int, k: int, d: int, itemsize: int = 1,
-                     vmem_budget: int = _VMEM_SCRATCH_BUDGET
-                     ) -> tuple[int, int]:
-    """(B_block, D_block) picker for the tiled kernel.
+def pick_block_b(b: int, k: int, d: int, itemsize: int = 1,
+                 vmem_budget: int = _VMEM_SCRATCH_BUDGET) -> int:
+    """B_block picker for the tiled kernel.
 
-    Analytic layer: D_block is the largest 128-aligned divisor of D
-    that is <= 512 (any divisor for small dims; a 128-aligned
-    *non-divisor* for awkward D > 512, handled by the kernels' edge
-    padding); B_block is the largest power of two <= B whose working
-    set — fp32 out tile + landing ring + scale/weight blocks — fits
-    the VMEM budget.  Measured autotune-cache hits and env overrides
-    layer on top (``resolve_block_sizes``).
+    Analytic layer: the largest power of two (>= 8) <= the 8-padded B
+    whose working set — fp32 out tile + landing ring of whole
+    lane-dense rows — fits the VMEM budget.  Measured autotune-cache
+    hits and the env override layer on top (``resolve_block_b``).
     """
-    return resolve_block_sizes(b, k, d, itemsize,
-                               vmem_budget=vmem_budget)
+    return resolve_block_b(b, k, d, itemsize, vmem_budget=vmem_budget)
 
 
 def dequant_bag_tpu(payload: Array, scales: Array, indices: Array,
                     weights: Array | None = None,
                     use_pallas: bool = True,
                     interpret: bool | None = None,
-                    block_b: int | None = None,
-                    block_d: int | None = None) -> Array:
+                    block_b: int | None = None) -> Array:
     if not use_pallas:
         return dequant_bag_ref(payload, scales, indices, weights)
     return dequant_bag_pallas(payload, scales, indices, weights,
-                              interpret=interpret,
-                              block_b=block_b, block_d=block_d)
+                              interpret=interpret, block_b=block_b)
 
 
 def _tier_split(packed: PackedStore, indices: Array):
@@ -218,13 +186,12 @@ def packed_lookup_fused(packed: PackedStore, indices: Array,
     kernel (the others skip it), so the sum is **bit-identical** to
     ``packed_store.lookup``.
 
-    ``use_pallas=None`` auto-selects: the fused kernel when the backend
-    compiles it for real, the jnp oracle under interpretation (where
-    the interpreter's per-step Python loop would throttle serving).
+    ``use_pallas=None`` resolves through ``kernels.use_kernel``: the
+    kernel on a TPU backend, always; the jnp oracle only off the TPU
+    under interpretation (where the interpreter would throttle
+    serving).
     """
-    if use_pallas is None:
-        use_pallas = not should_interpret(interpret)
-    if not use_pallas:
+    if not use_kernel(use_pallas, interpret):
         from repro.core.packed_store import lookup
         return lookup(packed, indices)
     flat = indices.reshape(-1, 1)

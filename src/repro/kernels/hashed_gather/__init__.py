@@ -7,7 +7,8 @@ rows picked by a universal hash of ``(r, c, j)``; memory is bounded by
 the pool size ``S * Z``, independent of the vocabulary.
 
 ``ref``      jnp oracles + the hash family (``hash_slots``)
-``kernel``   the Pallas landing-ring forward (``hashed_gather_pallas``)
+``kernel``   the forward on the ``dequant_bag`` kernel
+             (``hashed_gather_pallas``)
 ``ops``      dispatch + block resolution (``hashed_gather``)
 ``autodiff`` the ``custom_vjp`` training twins
              (``hashed_bag_lookup_train`` / ``hashed_lookup_train``)

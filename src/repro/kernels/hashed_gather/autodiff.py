@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import should_interpret
+from repro.kernels import use_kernel
 from repro.kernels.dequant_bag.autodiff import bag_grad_tpu
 from repro.kernels.hashed_gather.kernel import hashed_gather_pallas
 from repro.kernels.hashed_gather.ref import hashed_gather_ref
@@ -97,8 +97,7 @@ def hashed_bag_lookup_train(pool: Array, indices: Array,
     in both directions).  Gradients w.r.t. ``pool`` run the scatter-add
     Pallas kernel; w.r.t. ``weights`` the sign-folded chunk-dot path.
     """
-    if use_pallas is None:
-        use_pallas = not should_interpret(interpret)
+    use_pallas = use_kernel(use_pallas, interpret)
     slots, coeff = slot_plan(indices, weights, num_chunks=num_chunks,
                              num_hashes=num_hashes,
                              num_slots=pool.shape[0], seed=seed)

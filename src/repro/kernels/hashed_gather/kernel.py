@@ -1,143 +1,26 @@
-"""Pallas TPU kernel: fused chunk-pool gather + sign/scale combine.
+"""Fused chunk-pool gather + sign/scale combine on the bag kernel.
 
 The hashed-store serving hot path.  XLA lowers a hashed materialization
 to gather(pool) -> gather(scale) -> multiply -> reshape -> segment-sum,
-materialising the (B, C*T, Z) chunk intermediate in HBM.  This kernel
-streams each needed pool chunk HBM->VMEM exactly once through the same
-double-buffered landing ring as ``dequant_bag`` and accumulates
-``(chunk * scale) * coeff`` straight into the output chunk tile — the
-intermediate never exists.
-
-Layout (``hashed_gather_pallas``):
-
-  grid = (ceil(B / B_block), C)           C = chunks per row
-  slots  (B, C*T) int32  scalar-prefetched (SMEM): pool-row addressing,
-                         T slots per (bag, chunk) — ``K * num_hashes``
-  scales (B_block, T)    VMEM block at chunk c: per-slot pool scales
-  coeff  (B_block, T)    VMEM block at chunk c: weight x hash sign
-                         (0 = padded/masked slot: DMA + accumulate skip)
-  pool   (S, Z)          stays in HBM (ANY); chunk rows DMA'd manually
-  out    (B_block, Z)    VMEM chunk tile of the (B, C*Z) output
-  scratch (nbuf, Z)      pool-dtype landing ring + per-buffer DMA sems
-
-Grid step (i, c) owns output columns [c*Z, (c+1)*Z) — a whole chunk —
-so each pool-row DMA copies a full (1, Z) pool row and the kernel
-needs no D-blocking: the chunk IS the tile.  Slots drain in t order
-per bag, so bags are bit-identical to the jnp oracle's per-chunk
-reduction order.  Accumulation reuses the exact bag reduction shape of
-``dequant_bag._tiled_kernel`` (prime ring, drain + refill with
-zero-coeff skip); only the addressing differs (chunk-local slot
-columns, full-row DMA).
+materialising the (B, C*T, Z) chunk intermediate in HBM.  Each output
+chunk ``out[b, c*Z:(c+1)*Z]`` is a bag of the ``T = K * num_hashes``
+pool rows in ``slots[b, c*T:(c+1)*T]``, scaled by the pool scales and
+the sign-folded coefficients — exactly ``dequant_bag``'s operation over
+the (S, Z) pool, with (bag, chunk) pairs as the bags.  So the forward
+runs ``dequant_bag_pallas`` on reshaped operands: one kernel for both
+stores, the same landing ring, lane-dense row view and zero-coefficient
+DMA skip, and the same per-bag t order as the jnp oracle's per-chunk
+reduction.  The backward (``autodiff``) is ``bag_grad`` on the same
+reshape.
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import should_interpret
+from repro.kernels.dequant_bag.kernel import dequant_bag_pallas
 
 Array = jax.Array
-
-
-def _hashed_kernel(idx_ref, scale_ref, coeff_ref, pool_ref, out_ref,
-                   rows_ref, sems, *, block_b: int, t: int, nbuf: int):
-    i = pl.program_id(0)
-    c = pl.program_id(1)
-    nslots = block_b * t
-
-    def row_dma(slot):
-        b, kk = slot // t, slot % t
-        row = idx_ref[i * block_b + b, c * t + kk]
-        buf = slot % nbuf
-        return pltpu.make_async_copy(
-            pool_ref.at[pl.ds(row, 1), :],
-            rows_ref.at[pl.ds(buf, 1), :],
-            sems.at[buf])
-
-    def start(slot):
-        @pl.when(coeff_ref[slot // t, slot % t] != 0.0)
-        def _():
-            row_dma(slot).start()
-
-    # prime the ring: the first nbuf slots' chunk copies go in flight
-    def warm(slot, carry):
-        start(slot)
-        return carry
-
-    jax.lax.fori_loop(0, min(nbuf, nslots), warm, 0)
-    out_ref[...] = jnp.zeros_like(out_ref)
-
-    def drain(slot, carry):
-        b, kk = slot // t, slot % t
-        w = coeff_ref[b, kk]
-
-        @pl.when(w != 0.0)
-        def _():
-            row_dma(slot).wait()
-            buf = slot % nbuf
-            row = rows_ref[pl.ds(buf, 1), :].astype(jnp.float32)
-            out_ref[pl.ds(b, 1), :] += (row * scale_ref[b, kk]) * w
-
-        # refill: slot+nbuf reuses this buffer, free exactly now
-        @pl.when(slot + nbuf < nslots)
-        def _():
-            start(slot + nbuf)
-        return carry
-
-    jax.lax.fori_loop(0, nslots, drain, 0)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("num_chunks", "block_b", "nbuf",
-                                    "interpret"))
-def _hashed_call(pool: Array, scales: Array, slots: Array,
-                 coeff: Array, *, num_chunks: int, block_b: int,
-                 nbuf: int, interpret: bool) -> Array:
-    s, z = pool.shape
-    b = slots.shape[0]
-    t = slots.shape[1] // num_chunks
-    slots = slots.astype(jnp.int32)
-    sg = jnp.take(scales, slots, axis=0).astype(jnp.float32)
-    coeff = coeff.astype(jnp.float32)
-
-    nb = -(-b // block_b)
-    bp = nb * block_b
-    if bp != b:
-        # grid padding: extra bags carry coeff 0, so every DMA and
-        # accumulate for them is skipped in-kernel
-        slots = jnp.pad(slots, ((0, bp - b), (0, 0)))
-        sg = jnp.pad(sg, ((0, bp - b), (0, 0)))
-        coeff = jnp.pad(coeff, ((0, bp - b), (0, 0)))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nb, num_chunks),
-        in_specs=[
-            pl.BlockSpec((block_b, t), lambda i, c, idx: (i, c)),
-            pl.BlockSpec((block_b, t), lambda i, c, idx: (i, c)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=pl.BlockSpec((block_b, z),
-                               lambda i, c, idx: (i, c)),
-        scratch_shapes=[
-            pltpu.VMEM((nbuf, z), pool.dtype),
-            pltpu.SemaphoreType.DMA((nbuf,)),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_hashed_kernel, block_b=block_b, t=t,
-                          nbuf=nbuf),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bp, num_chunks * z),
-                                       jnp.float32),
-        interpret=interpret,
-    )(slots, sg, coeff, pool)
-    return out[:b]
 
 
 def hashed_gather_pallas(pool: Array, scales: Array, slots: Array,
@@ -147,24 +30,22 @@ def hashed_gather_pallas(pool: Array, scales: Array, slots: Array,
                          nbuf: int | None = None) -> Array:
     """pool (S, Z), scales (S,), slots/coeff (B, C*T) -> (B, C*Z) fp32.
 
-    Tiled (B_block, chunk) kernel with the ``nbuf``-deep landing ring;
-    B_block defaults to ``ops.resolve_hashed_block_b`` (measured
-    autotune cache under the ``hashed_gather`` key, analytic VMEM model
-    underneath), ``nbuf`` to the shared ``dequant_bag`` resolver.
-    ``interpret`` defaults to backend auto-detection.
+    ``block_b`` counts requests (each one ``num_chunks`` kernel bags)
+    and defaults to ``ops.resolve_hashed_block_b`` (measured autotune
+    cache under the ``hashed_gather`` key, analytic VMEM model
+    underneath); ``nbuf`` and ``interpret`` as in ``dequant_bag``.
     """
     b = slots.shape[0]
     t = slots.shape[1] // num_chunks
-    from repro.kernels.dequant_bag.ops import resolve_nbuf
+    z = pool.shape[1]
     from repro.kernels.hashed_gather.ops import resolve_hashed_block_b
-    if block_b is None:
-        block_b = resolve_hashed_block_b(b, t, pool.shape[1],
-                                         pool.dtype.itemsize,
-                                         dtype=str(pool.dtype))
-    if nbuf is None:
-        nbuf = resolve_nbuf(block_b * t)
-    nbuf = max(1, min(int(nbuf), block_b * t))
-    return _hashed_call(pool, scales, slots, coeff,
-                        num_chunks=num_chunks, block_b=int(block_b),
-                        nbuf=nbuf,
-                        interpret=should_interpret(interpret))
+    # a request holds num_chunks * t slots of the kernel's bag block
+    block_b = resolve_hashed_block_b(b, num_chunks * t, z,
+                                     pool.dtype.itemsize, block_b,
+                                     dtype=str(pool.dtype))
+    out = dequant_bag_pallas(pool, scales,
+                             slots.reshape(b * num_chunks, t),
+                             coeff.reshape(b * num_chunks, t),
+                             interpret=interpret,
+                             block_b=block_b * num_chunks, nbuf=nbuf)
+    return out.reshape(b, num_chunks * z)

@@ -6,25 +6,19 @@ addressing — per-(bag, chunk) pool slots plus sign-folded coefficients
 oracle with the same auto-select rule as the dequant-bag family (the
 oracle under interpretation, the kernel where the backend compiles it).
 
-Block sizes layer the measured autotune cache (``kernels.autotune``,
-kind ``hashed_gather``) over the shared analytic VMEM model; the chunk
-width Z is the D-block by construction (one pool row per DMA), so only
-B_block is resolved.
+The bag block layers the measured autotune cache
+(``kernels.autotune``, kind ``hashed_gather``) over the shared
+analytic VMEM model; pool rows move whole, so only B_block is
+resolved.
 """
 
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import should_interpret
-from repro.kernels.dequant_bag.ops import (
-    _VMEM_SCRATCH_BUDGET,
-    _auto_block_b,
-    _cache_dtype,
-)
+from repro.kernels import use_kernel
+from repro.kernels.dequant_bag.ops import resolve_block_b
 from repro.kernels.hashed_gather.kernel import hashed_gather_pallas
 from repro.kernels.hashed_gather.ref import hash_slots, hashed_gather_ref
 
@@ -34,24 +28,11 @@ Array = jax.Array
 def resolve_hashed_block_b(b: int, t: int, z: int, itemsize: int = 4,
                            block_b: int | None = None,
                            dtype: str | None = None) -> int:
-    """B_block for the hashed kernel: argument, then
-    ``REPRO_DEQUANT_BLOCK_B`` (shared env knob), then a measured
-    autotune-cache hit for ``(backend, hashed_gather, dtype, b, t, z)``,
-    then the analytic VMEM-budget pick (Z doubles as D_block)."""
-    if block_b is not None:
-        if block_b < 1:
-            raise ValueError(f"block_b must be >= 1, got {block_b}")
-        return int(block_b)
-    env = os.environ.get("REPRO_DEQUANT_BLOCK_B")
-    if env:
-        return max(1, int(env))
-    from repro.kernels import autotune
-    cached = autotune.lookup_cached("hashed_gather",
-                                    _cache_dtype(itemsize, dtype),
-                                    b, t, z)
-    if cached is not None:
-        return int(cached[0])
-    return _auto_block_b(b, t, z, itemsize, _VMEM_SCRATCH_BUDGET)
+    """B_block (requests) for the hashed kernel: ``resolve_block_b``
+    under the ``hashed_gather`` autotune-cache key, with a request's
+    ``t`` slots as K and the chunk width Z as D."""
+    return resolve_block_b(b, t, z, itemsize, block_b,
+                           kind="hashed_gather", dtype=dtype)
 
 
 def slot_plan(indices: Array, weights: Array | None, *,
@@ -88,8 +69,7 @@ def hashed_gather(pool: Array, scales: Array, slots: Array,
     ``hashed_gather_ref``).  ``use_pallas=None`` auto-selects: the
     kernel when the backend compiles it for real, the oracle under
     interpretation."""
-    if use_pallas is None:
-        use_pallas = not should_interpret(interpret)
+    use_pallas = use_kernel(use_pallas, interpret)
     if not use_pallas:
         return hashed_gather_ref(pool, scales, slots, coeff,
                                  num_chunks=num_chunks)

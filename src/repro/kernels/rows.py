@@ -1,0 +1,185 @@
+"""Row-gather plumbing shared by the gather kernels (dequant_bag,
+bag_grad, bag_matmul, hashed_gather).
+
+Mosaic moves HBM data in (8, 128) tiles of 32-bit words: a DMA slice
+must span the whole lane (last) dimension, and on packed dtypes its
+row offset must be a multiple of the sublane packing.  A (V, 64) table
+in row-major HBM is a (V, 128)-padded buffer whose 64-wide row slice
+the compiler refuses.  So every kernel here reads tables through one
+**lane-dense view**:
+
+  * the row width D is padded to ``dp``: a power of two when D < 128
+    (so it divides 128), a multiple of 128 otherwise;
+  * ``r = 128 // dp`` logical rows share one 128-lane physical row
+    (r = 1 for dp >= 128): the table, zero-padded to ``r * P`` rows,
+    is cut into ``r`` consecutive blocks of ``P`` rows laid side by
+    side, a (P, r * dp) view;
+  * 32-bit tables move one physical row per DMA; bf16 / int8 tables
+    move the aligned group of ``g`` = 16 / 32 physical rows that holds
+    it (one packed (8, 128) tile), and the kernel picks the row out of
+    the landed tile on the VPU.
+
+Logical row ``i`` therefore lives in physical row ``i % P`` at lanes
+``[(i // P) * dp, (i // P + 1) * dp)``; ``read_row`` rolls it down to
+lanes ``[0, dp)``.  Blocks side by side (rather than interleaved rows)
+let XLA build the view from the column-major layout it gives narrow
+(V, 64) arrays in one transpose, with no lane-padded row-major copy
+in between.
+
+Per-slot scalars (row ids, scales, weights) are flattened to 1-D and
+streamed into SMEM one grid block at a time.  A 1-D SMEM block must be
+a multiple of 1024 words, so each block's slot list is zero-padded to
+that (padding slots carry weight 0 and are never visited).
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+Array = jax.Array
+
+LANES = 128
+SUBLANES = 8
+# a 1-D SMEM block must be a multiple of this many words
+SLOT_ALIGN = 1024
+# cap on live slots per grid block: three 4-byte SMEM streams, double
+# buffered, stay under 200 KiB of the 1 MiB scalar memory
+MAX_BLOCK_SLOTS = 8192
+
+
+def row_layout(d: int) -> tuple[int, int]:
+    """(dp, r): padded row width and logical rows per physical row."""
+    if d >= LANES:
+        return -(-d // LANES) * LANES, 1
+    dp = 1 << max(0, d - 1).bit_length()
+    return dp, LANES // dp
+
+
+def dma_group(dtype) -> int:
+    """Physical rows per DMA: one for 32-bit tables, one whole packed
+    tile (16 bf16 / 32 int8 rows) for narrower dtypes."""
+    return SUBLANES * (4 // jnp.dtype(dtype).itemsize)
+
+
+def phys_rows(v: int, d: int, dtype) -> int:
+    """P: physical rows of the lane-dense view of a (V, D) table,
+    padded to a multiple of the DMA group so group reads stay in
+    bounds."""
+    dp, r = row_layout(d)
+    g = dma_group(dtype)
+    return -(-(-(-v // r)) // g) * g
+
+
+def lane_dense(table: Array) -> Array:
+    """(V, D) -> the (P, r * dp) physical view read by the kernels.
+    A no-op for 32-bit tables whose D is a multiple of 128 and whose V
+    is a multiple of 8."""
+    v, d = table.shape
+    dp, r = row_layout(d)
+    p = phys_rows(v, d, table.dtype)
+    if dp != d or p * r != v:
+        table = jnp.pad(table, ((0, p * r - v), (0, dp - d)))
+    if r == 1:
+        return table
+    if jnp.dtype(table.dtype).itemsize < 4:
+        # the fastest form to compile and run for packed dtypes
+        return jnp.concatenate([table[s * p:(s + 1) * p]
+                                for s in range(r)], axis=1)
+    return jnp.transpose(table.T.reshape(dp, r, p), (2, 1, 0)).reshape(
+        p, r * dp)
+
+
+def from_lane_dense(phys: Array, v: int, d: int) -> Array:
+    """Inverse of ``lane_dense``: (P, r * dp) -> (V, D)."""
+    dp, r = row_layout(d)
+    if r > 1:
+        p = phys.shape[0]
+        phys = jnp.transpose(phys.reshape(p, r, dp), (2, 1, 0)).reshape(
+            dp, r * p).T
+    return phys[:v, :d]
+
+
+def legal_block_b(block_b: int) -> int:
+    """Round a bag-block size up to the 8-sublane rule of the (block_b,
+    ...) output tiles."""
+    return max(SUBLANES, -(-int(block_b) // SUBLANES) * SUBLANES)
+
+
+def block_slots(n: int) -> int:
+    """SMEM block length for ``n`` live slots: the next multiple of
+    ``SLOT_ALIGN``."""
+    return -(-n // SLOT_ALIGN) * SLOT_ALIGN
+
+
+def flatten_slots(arrays, block_b: int) -> tuple[list[Array], int]:
+    """(Bp, S) per-slot arrays -> 1-D, one ``block_slots`` span per
+    block of ``block_b`` rows (row-major slot order inside a block)."""
+    bp, s = arrays[0].shape
+    nb = bp // block_b
+    n = block_b * s
+    span = block_slots(n)
+    out = [jnp.pad(a.reshape(nb, n), ((0, 0), (0, span - n))).reshape(-1)
+           for a in arrays]
+    return out, span
+
+
+def slot_spec(span: int) -> pl.BlockSpec:
+    return pl.BlockSpec((span,), lambda i, *_: (i,),
+                        memory_space=pltpu.SMEM)
+
+
+def ring_shape(nbuf: int, g: int, width: int) -> tuple[int, ...]:
+    return (nbuf, width) if g == 1 else (nbuf, g, width)
+
+
+def _split(row, r: int, prows: int):
+    """Logical row -> (physical row, segment)."""
+    if r == 1:
+        return row, 0
+    return row % prows, row // prows
+
+
+def row_copy(src_hbm, ring, sems, buf, row, *, r: int, g: int,
+             prows: int):
+    """Async copy of the physical rows holding logical ``row`` into
+    ring buffer ``buf``."""
+    p, _ = _split(row, r, prows)
+    if g == 1:
+        return pltpu.make_async_copy(src_hbm.at[pl.ds(p, 1), :],
+                                     ring.at[pl.ds(buf, 1), :],
+                                     sems.at[buf])
+    base = pl.multiple_of((p // g) * g, g)
+    return pltpu.make_async_copy(src_hbm.at[pl.ds(base, g), :],
+                                 ring.at[buf], sems.at[buf])
+
+
+def read_row(ring, buf, row, *, r: int, g: int, dp: int,
+             prows: int) -> Array:
+    """The landed logical ``row`` as fp32 (1, dp)."""
+    p, seg = _split(row, r, prows)
+    if g == 1:
+        x = ring[pl.ds(buf, 1), :].astype(jnp.float32)
+    else:
+        tile = ring[buf].astype(jnp.float32)               # (g, W)
+        pick = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 0) == p % g
+        x = jnp.sum(jnp.where(pick, tile, 0.0), axis=0, keepdims=True)
+    if r > 1:
+        # segment -> lanes [0, dp)
+        x = pltpu.roll(x, (LANES - seg * dp) % LANES, 1)[:, :dp]
+    return x
+
+
+def place_row(x: Array, row, *, r: int, dp: int, prows: int) -> Array:
+    """(1, LANES) with lanes [0, dp) holding the value -> the same
+    value at logical ``row``'s lanes of its physical row (zeros
+    elsewhere, given zeros beyond dp on input)."""
+    if r == 1:
+        return x
+    return pltpu.roll(x, _split(row, r, prows)[1] * dp, 1)
+
+
+def phys_row(row, *, r: int, prows: int):
+    return _split(row, r, prows)[0]

@@ -5,6 +5,27 @@ from __future__ import annotations
 import os
 
 
+def use_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at its one place and
+    return that directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing else is set.  Otherwise the cache is ``<repo>/.jax_cache``:
+    a fixed path (the path is part of the cache key, so a per-run
+    directory would never hit), listed in ``.gitignore``.  Call after
+    any ``XLA_FLAGS`` set-up: this imports jax.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    from repro import REPO_ROOT
+    path = str(REPO_ROOT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def force_host_device_count(n: int) -> None:
     """Fake ``n`` host devices for a CPU-container mesh run.
 

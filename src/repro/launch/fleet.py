@@ -91,14 +91,14 @@ def _main() -> None:
     if not counts or min(counts) < 1:
         ap.error("--replicas needs positive integers")
 
+    from repro.launch import use_compile_cache
+    use_compile_cache()
     import jax
     import jax.numpy as jnp
 
     from repro import configs
-    from repro.core import FQuantConfig
-    from repro.core import qat_store as qs
     from repro.core.packed_store import lookup_fused
-    from repro.core.tiers import plan_thresholds_for_ratio
+    from repro.launch.serve import build_serving_store
     from repro.models import embedding as E
     from repro.serve import (Fleet, FleetConfig, OnlineConfig,
                              OnlineServer, Replica, drifting_zipf_batch,
@@ -109,20 +109,14 @@ def _main() -> None:
     if arch.family != "recsys" or arch.seq_model:
         raise SystemExit("fleet driver supports field-based recsys "
                          "archs")
-    model = arch.smoke_model
+    # the smoke config on every backend: each replica holds its own
+    # packed store (~1.4 GB at the chip config), so 8 replicas of the
+    # chip config would not fit one 16 GB chip, and no fleet run on a
+    # chip exists yet
+    model, num_dense, _ = arch.driver_model(chip=False)
     spec = model.spec
     params = model.init(jax.random.PRNGKey(0))
-    num_dense = arch.smoke_num_dense if arch.has_dense else 0
-
-    rng = np.random.default_rng(0)
-    pri = jnp.asarray((rng.pareto(1.2, spec.total_rows) * 10)
-                      .astype(np.float32))
-    cfg = FQuantConfig(
-        tiers=plan_thresholds_for_ratio(pri, spec.dim, 0.5),
-        stochastic=False)
-    store = qs.QATStore(params["embed_table"], pri)
-    store = store._replace(table=qs.snap(
-        store.table, qs.current_tiers(store, cfg), cfg))
+    store, cfg = build_serving_store(spec, params["embed_table"], seed=0)
 
     cards = np.asarray(spec.cardinalities, np.int64)
     offsets = np.asarray(spec.offsets(), np.int64)

@@ -54,7 +54,7 @@ class PipelineConfig:
     arch: str = "dlrm-rm2"
     steps: int = 120
     batch: int = 64
-    lr: float = 0.05
+    lr: float | None = None      # None: the arch's driver rate
     mesh: int = 1
     ckpt_dir: str = "/tmp/repro_pipeline"
     ckpt_every: int = 40
@@ -124,7 +124,8 @@ def run_pipeline(cfg: PipelineConfig) -> dict:
     arch = configs.get(cfg.arch)
     mesh = None
     if cfg.mesh > 1:
-        mesh = jax.make_mesh((cfg.mesh,), ("model",))
+        from repro.launch.mesh import make_model_mesh
+        mesh = make_model_mesh(cfg.mesh)
     fq_train = FQuantConfig()            # paper-default thresholds
 
     setup = build_recsys_training(
@@ -132,7 +133,7 @@ def run_pipeline(cfg: PipelineConfig) -> dict:
         fq_cfg=fq_train, use_pallas=cfg.use_pallas)
     model, spec, batch_fn = setup.model, setup.spec, setup.batch_fn
     indices_fn = setup.indices_fn
-    num_dense = arch.smoke_num_dense if arch.has_dense else 0
+    num_dense = setup.num_dense
 
     rec: dict = {"schema": "bench_pipeline/v1", "benchmark": "pipeline",
                  "arch": cfg.arch, "mesh": cfg.mesh,
@@ -210,7 +211,7 @@ def run_pipeline(cfg: PipelineConfig) -> dict:
     if pruned.size and cfg.finetune_steps:
         ft_step = make_compressed_train_step(
             model.loss_from_emb, indices_fn, lambda b: b["labels"],
-            "embed_table", cfg.lr, spec.num_fields, fq_cfg=fq_train,
+            "embed_table", setup.lr, spec.num_fields, fq_cfg=fq_train,
             mesh=mesh, use_pallas=cfg.use_pallas, with_accum=True,
             field_mask=jnp.asarray(mask, jnp.float32))
         jft = jax.jit(ft_step)
@@ -431,8 +432,10 @@ def _main() -> None:
                          "+ a final snapshot); docs/observability.md")
     args = ap.parse_args()
 
-    from repro.launch import force_host_device_count
+    from repro.launch import (force_host_device_count,
+                              use_compile_cache)
     force_host_device_count(args.mesh)
+    use_compile_cache()
 
     if args.metrics_out:
         from repro.serve.loop import SERVE_PHASES

@@ -1,14 +1,16 @@
 """Serving driver: ``python -m repro.launch.serve --arch dlrm-rm2``.
 
-Builds the packed tier-partitioned store for a (smoke-sized) recsys model
-and serves a batched request stream, reporting latency percentiles and
-the memory/bytes ratios behind the paper's QPS claim.
+Builds the packed tier-partitioned store for a recsys model and serves
+a batched request stream, reporting latency percentiles and the
+memory/bytes ratios behind the paper's QPS claim.  On a TPU the model
+is the arch's chip config (published widths, the chip's share of the
+vocabulary — ``RecsysArch.driver_model``); elsewhere its smoke config.
 
 ``--mesh N`` (N > 1) row-shards the PackedStore over an N-way "model"
 mesh and serves through ``repro.dist.packed.sharded_lookup`` — the
-distributed serving path.  On this CPU container the mesh is faked with
-``--xla_force_host_platform_device_count`` (set before jax initialises),
-so 1/2/4-way runs are a smoke/QPS-scaling proxy for a real TPU mesh.
+distributed serving path.  On a CPU backend the mesh is faked with
+``--xla_force_host_platform_device_count`` (set before jax initialises);
+on a TPU it needs N real devices and fails otherwise.
 
 ``--online`` switches to the ``repro.serve`` subsystem: a drifting-zipf
 request stream is served cache-first (``--cache-rows`` hot rows in
@@ -53,6 +55,34 @@ import json
 import numpy as np
 
 from repro import obs
+
+
+def build_serving_store(spec, table, seed: int = 0):
+    """The QAT store the drivers serve: a zipf-like priority profile
+    drawn from ``seed``, Eq. 8 thresholds planned for a 50% memory
+    budget, and every row snapped to its tier.  Returns (store, cfg);
+    ``OnlineServer`` / ``pack`` turn it into the packed store."""
+    import jax.numpy as jnp
+
+    from repro.core import FQuantConfig
+    from repro.core import qat_store as qs
+    from repro.core.tiers import plan_thresholds_for_ratio
+
+    rng = np.random.default_rng(seed)
+    pri = jnp.asarray((rng.pareto(1.2, spec.total_rows) * 10)
+                      .astype(np.float32))
+    cfg = FQuantConfig(
+        tiers=plan_thresholds_for_ratio(pri, spec.dim, 0.5),
+        stochastic=False)
+    store = qs.QATStore(table, pri)
+    tiers = qs.current_tiers(store, cfg)
+    # snap in row chunks: each eager step of snap holds a full-size
+    # buffer, too many at once for one chip at published widths
+    chunk = 1 << 20
+    store = store._replace(table=jnp.concatenate(
+        [qs.snap(table[i:i + chunk], tiers[i:i + chunk], cfg)
+         for i in range(0, table.shape[0], chunk)]))
+    return store, cfg
 
 
 def main() -> None:
@@ -201,8 +231,10 @@ def _main() -> None:
         import os
         os.environ["REPRO_AUTOTUNE_CACHE"] = args.autotune_cache
 
-    from repro.launch import force_host_device_count
+    from repro.launch import (force_host_device_count,
+                              use_compile_cache)
     force_host_device_count(args.mesh)
+    use_compile_cache()
 
     import jax
     import jax.numpy as jnp
@@ -218,34 +250,28 @@ def _main() -> None:
                                    every=args.metrics_every))
 
     from repro import configs
-    from repro.core import FQuantConfig, pack
-    from repro.core import qat_store as qs
+    from repro.core import pack
     from repro.core.packed_store import lookup_fused as packed_lookup
-    from repro.core.tiers import plan_thresholds_for_ratio
     from repro.models import embedding as E
 
     arch = configs.get(args.arch)
     if arch.family != "recsys" or arch.seq_model:
         raise SystemExit("serve driver supports field-based recsys archs")
-    model = arch.smoke_model
+    model, num_dense, _ = arch.driver_model()
     spec = model.spec
     params = model.init(jax.random.PRNGKey(0))
+    dev = jax.devices()[0]
+    where = f"{dev.platform} {dev.device_kind}, mesh={args.mesh}"
 
     # fabricate a zipf priority profile and pack at a 50% budget
-    rng = np.random.default_rng(0)
-    pri = jnp.asarray((rng.pareto(1.2, spec.total_rows) * 10)
-                      .astype(np.float32))
-    cfg = FQuantConfig(
-        tiers=plan_thresholds_for_ratio(pri, spec.dim, 0.5),
-        stochastic=False)
-    store = qs.QATStore(params["embed_table"], pri)
-    store = store._replace(table=qs.snap(
-        store.table, qs.current_tiers(store, cfg), cfg))
+    store, cfg = build_serving_store(spec, params["embed_table"])
+    pri = store.priority
     fp32 = spec.total_rows * spec.dim * 4
 
     mesh = None
     if args.mesh > 1:
-        mesh = jax.make_mesh((args.mesh,), ("model",))
+        from repro.launch.mesh import make_model_mesh
+        mesh = make_model_mesh(args.mesh)
 
     f = spec.num_fields
     cards = np.asarray(spec.cardinalities, np.int64)
@@ -264,7 +290,7 @@ def _main() -> None:
         if arch.has_dense:
             rr = np.random.default_rng(10_000 + r)
             batch["dense"] = jnp.asarray(rr.standard_normal(
-                (args.batch, arch.smoke_num_dense)).astype(np.float32))
+                (args.batch, num_dense)).astype(np.float32))
         return batch
 
     rec = {"arch": args.arch, "batch": args.batch,
@@ -329,7 +355,6 @@ def _main() -> None:
               f"({packed_bytes / fp32:.1%} of fp32), "
               f"cache {args.cache_rows} rows, "
               f"retier every {args.retier_every} requests")
-        num_dense = arch.smoke_num_dense if arch.has_dense else 0
         if args.serve_batch > 0:
             if tiers_at_pack is not None:
                 rec.update(stream_bytes_per_request(
@@ -363,8 +388,7 @@ def _main() -> None:
               f"steady {result.steady_qps:.0f} qps "
               f"hit-rate {server.stats.hit_rate:.1%} "
               f"retiers {server.stats.retiers} "
-              f"rows moved {server.stats.rows_moved} (host CPU, "
-              f"mesh={args.mesh})")
+              f"rows moved {server.stats.rows_moved} ({where})")
         rec.update(result.as_dict())
         rec.update({"cache_rows": args.cache_rows,
                     "retier_every": args.retier_every,
@@ -437,8 +461,7 @@ def _main() -> None:
     p99 = float(np.percentile(lat_us, 99))
     qps = args.batch / (np.mean(lat_us) / 1e6)
     print(f"{args.requests} requests x{args.batch}: "
-          f"p50 {p50:.0f}us p99 {p99:.0f}us (host CPU, "
-          f"mesh={args.mesh})")
+          f"p50 {p50:.0f}us p99 {p99:.0f}us ({where})")
     rec.update({"qps": round(qps, 1),
                 "p50_us": round(p50, 1), "p99_us": round(p99, 1),
                 "packed_mib": round(packed_mib, 3),

@@ -25,7 +25,9 @@ def main() -> None:
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--batch", type=int, default=64)
-    ap.add_argument("--lr", type=float, default=0.05)
+    ap.add_argument("--lr", type=float, default=None,
+                    help="default: the arch's driver rate for the model "
+                         "it runs (RecsysArch.driver_model)")
     ap.add_argument("--mesh", type=int, default=1)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train")
     ap.add_argument("--ckpt-every", type=int, default=25)
@@ -34,8 +36,10 @@ def main() -> None:
                          "for recsys archs")
     args = ap.parse_args()
 
-    from repro.launch import force_host_device_count
+    from repro.launch import (force_host_device_count,
+                              use_compile_cache)
     force_host_device_count(args.mesh)
+    use_compile_cache()
 
     import jax
 
@@ -60,7 +64,8 @@ def main() -> None:
 
     model_mesh = None
     if args.mesh > 1:
-        model_mesh = jax.make_mesh((args.mesh,), ("model",))
+        from repro.launch.mesh import make_model_mesh
+        model_mesh = make_model_mesh(args.mesh)
     setup = build_recsys_training(arch, batch=args.batch, lr=args.lr,
                                   mesh=model_mesh)
 
@@ -76,7 +81,7 @@ def main() -> None:
               f"already at step {args.steps} "
               f"(resumed_from={result.resumed_from})")
         return
-    print(f"trained {result.steps_run} steps "
+    print(f"trained {result.steps_run} steps at lr {setup.lr} "
           f"(resumed_from={result.resumed_from}): "
           f"loss {result.losses[0]:.4f} -> {result.losses[-1]:.4f}, "
           f"stragglers {result.stragglers}, nan_skips "

@@ -71,6 +71,9 @@ class LoopResult(NamedTuple):
                                   # (0.0 when the stream had none) —
                                   # the number the tail budget gates
     stats: dict           # ServeStats.as_dict() snapshot
+    last_out: object = None  # what serve_fn returned for the last batch
+    forward: object = None   # (jitted forward, its last call's args):
+                             # lower it to inspect the served program
 
     def as_dict(self) -> dict:
         d = {"qps": round(self.qps, 1),
@@ -218,6 +221,7 @@ def run_microbatched_loop(server: OnlineServer,
     first = np.asarray(make_request(0), np.int32).reshape(-1)
     batcher = MicroBatcher(serve_batch, first.shape[0])
     lat, counts, retiered, retier_s, window = [], [], [], [], []
+    last: list = [None]
 
     def run_batch(mb: MicroBatch) -> None:
         n_retiers = server.stats.retiers
@@ -226,7 +230,7 @@ def run_microbatched_loop(server: OnlineServer,
         s0 = server.stats.swaps
         active0 = server.shadow is not None
         with obs.timeblock("serve.request") as tb:
-            tb.sync(serve_fn(mb))
+            last[0] = tb.sync(serve_fn(mb))
         lat.append(tb.seconds)
         counts.append(mb.count)
         retiered.append(server.stats.retiers > n_retiers)
@@ -264,7 +268,7 @@ def run_microbatched_loop(server: OnlineServer,
         p50_us=p50, p95_us=p95, p99_us=p99,
         p99_retier_attributed=attributed,
         p99_while_retiering=p99_while,
-        stats=server.stats.as_dict())
+        stats=server.stats.as_dict(), last_out=last[0])
 
 
 def run_loop(server: OnlineServer,
@@ -475,8 +479,9 @@ def serve_forward_microbatched(server: OnlineServer, model, spec,
             valid = jnp.asarray(mb.valid)
             last["a"] = (b, valid)
         with obs.span("serve.lookup"):
-            out, hits, gidx = fwd(server.packed, server.cache, params, b,
-                                  valid)
+            args = (server.packed, server.cache, params, b, valid)
+            last["call"] = args
+            out, hits, gidx = fwd(*args)
             jax.block_until_ready(out)
         with obs.span("serve.combine"):
             server.observe(gidx, int(hits), valid=mb.valid[:, None],
@@ -484,11 +489,12 @@ def serve_forward_microbatched(server: OnlineServer, model, spec,
         return out
 
     cards = np.asarray(spec.cardinalities, np.int64)
-    return run_microbatched_loop(
+    res = run_microbatched_loop(
         server, serve_fn,
         lambda r: drifting_zipf_batch(cards, 1, r, requests, a=a,
                                       drift=drift, seed=seed)[0],
         requests, serve_batch)
+    return res._replace(forward=(fwd, last.get("call")))
 
 
 def serve_forward(server: OnlineServer, model, spec, params, *,

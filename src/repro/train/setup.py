@@ -33,6 +33,8 @@ class RecsysTrainSetup(NamedTuple):
     state: TrainState       # initial state, placed under the mesh
     batch_fn: Callable      # step index -> jnp batch dict
     indices_fn: Callable    # batch -> (B, F) global row ids
+    num_dense: int          # dense features per example (0: none)
+    lr: float               # the rate the step trains at
 
 
 def place_train_state(state: TrainState, mesh,
@@ -58,7 +60,7 @@ def place_train_state(state: TrainState, mesh,
                           accum=accum)
 
 
-def build_recsys_training(arch, *, batch: int, lr: float = 0.05,
+def build_recsys_training(arch, *, batch: int, lr: float | None = None,
                           mesh=None, axis: str = "model",
                           seed: int = 0,
                           fq_cfg: FQuantConfig | None = None,
@@ -67,18 +69,21 @@ def build_recsys_training(arch, *, batch: int, lr: float = 0.05,
     """Dataset + compressed train step + placed initial state.
 
     ``arch`` must be a field-based recsys Arch (raises SystemExit
-    otherwise, as the drivers' CLI contract).  Under a mesh the axis
-    size must divide the stacked table's rows.
+    otherwise, as the drivers' CLI contract).  The model is
+    ``arch.driver_model()``: published widths with the chip's share of
+    the vocabulary on a TPU, the smoke config elsewhere; ``lr=None``
+    trains at that model's driver rate.  Under a mesh the axis size
+    must divide the stacked table's rows.
     """
     if arch.family != "recsys" or arch.seq_model:
         raise SystemExit("compressed training supports field-based "
                          "recsys archs")
-    model = arch.smoke_model
+    model, num_dense, driver_lr = arch.driver_model()
+    lr = driver_lr if lr is None else lr
     spec = model.spec
     if mesh is not None and spec.total_rows % mesh.shape[axis]:
         raise SystemExit(f"table rows {spec.total_rows} not divisible "
                          f"by mesh axis {axis}={mesh.shape[axis]}")
-    num_dense = arch.smoke_num_dense if arch.has_dense else 0
     ds = CriteoSynth(CriteoConfig(
         num_fields=spec.num_fields,
         cardinalities=tuple(int(c) for c in spec.cardinalities),
@@ -101,4 +106,5 @@ def build_recsys_training(arch, *, batch: int, lr: float = 0.05,
 
     return RecsysTrainSetup(model=model, spec=spec, ds=ds, step=step,
                             state=state, batch_fn=batch_fn,
-                            indices_fn=indices_fn)
+                            indices_fn=indices_fn, num_dense=num_dense,
+                            lr=lr)

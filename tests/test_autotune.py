@@ -13,10 +13,7 @@ import pathlib
 import pytest
 
 from repro.kernels import autotune
-from repro.kernels.dequant_bag.ops import (
-    _auto_block_d,
-    resolve_block_sizes,
-)
+from repro.kernels.dequant_bag.ops import resolve_block_b
 
 
 @pytest.fixture
@@ -27,42 +24,43 @@ def cache(tmp_path, monkeypatch):
 
 
 def test_store_lookup_roundtrip_preserves_entries(cache):
-    autotune.store("dequant_bag", "int8", 64, 8, 64, 16, 64, 123.4)
+    autotune.store("dequant_bag", "int8", 64, 8, 64, {"block_b": 16},
+                   123.4)
     assert autotune.lookup_cached("dequant_bag", "int8",
-                                  64, 8, 64) == (16, 64)
+                                  64, 8, 64) == (16,)
     doc = json.loads(cache.read_text())
     assert doc["schema"] == "autotune_cache/v1"
     # a second store merges: the first entry survives
-    autotune.store("dequant_bag", "int8", 32, 4, 96, 8, 96, 50.0)
+    autotune.store("dequant_bag", "int8", 32, 4, 96, {"block_b": 8}, 50.0)
     assert autotune.lookup_cached("dequant_bag", "int8",
-                                  64, 8, 64) == (16, 64)
+                                  64, 8, 64) == (16,)
     assert autotune.lookup_cached("dequant_bag", "int8",
-                                  32, 4, 96) == (8, 96)
+                                  32, 4, 96) == (8,)
 
 
 def test_resolve_serves_cache_hit(cache):
     b, k, d = 64, 8, 64
-    analytic = resolve_block_sizes(b, k, d, 1)
-    tuned = (max(1, analytic[0] // 2), analytic[1])
+    analytic = resolve_block_b(b, k, d, 1)
+    tuned = max(8, analytic // 2)
     assert tuned != analytic
-    autotune.store("dequant_bag", "int8", b, k, d, *tuned, 1.0)
-    assert resolve_block_sizes(b, k, d, 1) == tuned
+    autotune.store("dequant_bag", "int8", b, k, d, {"block_b": tuned}, 1.0)
+    assert resolve_block_b(b, k, d, 1) == tuned
 
 
 def test_key_mismatch_is_a_miss_not_a_stale_hit(cache):
     b, k, d = 64, 8, 64
-    analytic = resolve_block_sizes(b, k, d, 1)
-    autotune.store("dequant_bag", "int8", b, k, d, 2, 32, 1.0)
+    analytic = resolve_block_b(b, k, d, 1)
+    autotune.store("dequant_bag", "int8", b, k, d, {"block_b": 8}, 1.0)
+    assert analytic != 8
     # different shape / kind / dtype: every probe misses and the
-    # resolver re-derives the analytic pick instead of serving (2, 32)
+    # resolver re-derives the analytic pick instead of serving 8
     assert autotune.lookup_cached("dequant_bag", "int8",
                                   b, k, d + 1) is None
     assert autotune.lookup_cached("bag_grad", "float32", b, k, d) is None
     assert autotune.lookup_cached("dequant_bag", "bfloat16",
                                   b, k, d) is None
-    assert resolve_block_sizes(b, k, d + 64, 1) == \
-        resolve_block_sizes(b, k, d + 64, 1, block_b=None)
-    assert resolve_block_sizes(b, k, d, 1, kind="bag_grad") == analytic
+    assert resolve_block_b(b, k, d + 64, 1) != 8
+    assert resolve_block_b(b, k, d, 1, kind="bag_grad") == analytic
 
 
 @pytest.mark.parametrize("content", [
@@ -73,53 +71,46 @@ def test_key_mismatch_is_a_miss_not_a_stale_hit(cache):
 ])
 def test_corrupt_or_stale_cache_falls_back(cache, content):
     b, k, d = 64, 8, 64
-    analytic = resolve_block_sizes(b, k, d, 1)
+    analytic = resolve_block_b(b, k, d, 1)
     cache.write_text(content)
     assert autotune.lookup_cached("dequant_bag", "int8", b, k, d) is None
-    assert resolve_block_sizes(b, k, d, 1) == analytic
+    assert resolve_block_b(b, k, d, 1) == analytic
 
 
 def test_malformed_entry_is_a_miss(cache):
     b, k, d = 64, 8, 64
+    analytic = resolve_block_b(b, k, d, 1)
     key = autotune.cache_key("dequant_bag", "int8", b, k, d)
-    cache.write_text(json.dumps({
-        "schema": "autotune_cache/v1",
-        "entries": {key: {"block_b": "four", "block_d": 0}},
-    }))
-    assert autotune.lookup_cached("dequant_bag", "int8", b, k, d) is None
-    assert resolve_block_sizes(b, k, d, 1) == \
-        resolve_block_sizes(b, k, d, 1, block_b=None, block_d=None)
+    for bad in ({"block_b": "four"}, {"block_b": 0}, {"us": 1.0}):
+        cache.write_text(json.dumps({"schema": "autotune_cache/v1",
+                                     "entries": {key: bad}}))
+        assert autotune.lookup_cached("dequant_bag", "int8",
+                                      b, k, d) is None
+        assert resolve_block_b(b, k, d, 1) == analytic
 
 
 def test_env_override_wins_over_cache(cache, monkeypatch):
     b, k, d = 64, 8, 64
-    autotune.store("dequant_bag", "int8", b, k, d, 2, 32, 1.0)
-    assert resolve_block_sizes(b, k, d, 1) == (2, 32)
-    monkeypatch.setenv("REPRO_DEQUANT_BLOCK_B", "4")
-    # ANY pinned dimension disqualifies the jointly-tuned cache pair:
-    # D must come back analytic, not the cached 32
-    assert resolve_block_sizes(b, k, d, 1) == (4, _auto_block_d(d))
-    monkeypatch.setenv("REPRO_DEQUANT_BLOCK_D", "16")
-    assert resolve_block_sizes(b, k, d, 1) == (4, 16)
+    autotune.store("dequant_bag", "int8", b, k, d, {"block_b": 16}, 1.0)
+    assert resolve_block_b(b, k, d, 1) == 16
+    monkeypatch.setenv("REPRO_DEQUANT_BLOCK_B", "32")
+    assert resolve_block_b(b, k, d, 1) == 32
     monkeypatch.delenv("REPRO_DEQUANT_BLOCK_B")
-    bb, bd = resolve_block_sizes(b, k, d, 1)
-    assert bd == 16 and bb != 2  # B re-sized against env D, cache out
+    assert resolve_block_b(b, k, d, 1) == 16
 
 
 def test_explicit_args_win_over_everything(cache, monkeypatch):
     b, k, d = 64, 8, 64
-    autotune.store("dequant_bag", "int8", b, k, d, 2, 32, 1.0)
-    monkeypatch.setenv("REPRO_DEQUANT_BLOCK_B", "4")
-    monkeypatch.setenv("REPRO_DEQUANT_BLOCK_D", "16")
-    assert resolve_block_sizes(b, k, d, 1, block_b=8, block_d=64) == \
-        (8, 64)
+    autotune.store("dequant_bag", "int8", b, k, d, {"block_b": 16}, 1.0)
+    monkeypatch.setenv("REPRO_DEQUANT_BLOCK_B", "32")
+    assert resolve_block_b(b, k, d, 1, block_b=8) == 8
 
 
 def test_empty_env_disables_cache(tmp_path, monkeypatch):
     monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", "")
     assert autotune.cache_path() is None
-    assert autotune.store("dequant_bag", "int8", 8, 2, 32, 1, 32,
-                          1.0) is None
+    assert autotune.store("dequant_bag", "int8", 8, 2, 32,
+                          {"block_b": 8}, 1.0) is None
     assert autotune.lookup_cached("dequant_bag", "int8", 8, 2,
                                   32) is None
 
@@ -132,17 +123,18 @@ def test_external_write_picked_up_without_restart(cache):
     key = autotune.cache_key("dequant_bag", "int8", b, k, d)
     cache.write_text(json.dumps({
         "schema": "autotune_cache/v1",
-        "entries": {key: {"block_b": 4, "block_d": 64, "us": 9.0}},
+        "entries": {key: {"block_b": 24, "us": 9.0}},
     }))
     assert autotune.lookup_cached("dequant_bag", "int8",
-                                  b, k, d) == (4, 64)
+                                  b, k, d) == (24,)
+    assert resolve_block_b(b, k, d, 1) == 24
 
 
 def test_bag_matmul_key_folds_output_width(cache):
     from repro.kernels.bag_matmul.ops import resolve_bm_block_sizes
     b, k, d, h = 64, 8, 64, 32
-    autotune.store("bag_matmul", "int8", b, k, d, 8, 16, 1.0,
-                   extra=f"|h={h}")
+    autotune.store("bag_matmul", "int8", b, k, d,
+                   {"block_b": 8, "block_h": 16}, 1.0, extra=f"|h={h}")
     assert resolve_bm_block_sizes(b, k, d, h, 1) == (8, 16)
     # same (b, k, d) with a different H is a distinct key: miss
     analytic = resolve_bm_block_sizes(b, k, d, 2 * h, 1)
@@ -150,29 +142,33 @@ def test_bag_matmul_key_folds_output_width(cache):
 
 
 def test_candidate_tilings_lead_with_analytic(cache):
-    b, k, d = 64, 8, 64
-    cands = autotune.candidate_tilings(b, k, d, 1)
-    assert cands[0] == resolve_block_sizes(b, k, d, 1)
-    assert len(cands) == len(set(cands))
-    assert all(1 <= bb <= b and bd >= 1 for bb, bd in cands)
+    for b, k, d, itemsize in [(64, 8, 64, 1), (5, 3, 96, 4),
+                              (4096, 26, 64, 2)]:
+        cands = autotune.candidate_block_b(b, k, d, itemsize)
+        assert cands[0] == resolve_block_b(b, k, d, itemsize)
+        assert len(cands) == len(set(cands))
+        # every candidate is a block that runs as named: the 8-row
+        # tile rule, never past the 8-padded batch
+        assert all(bb % 8 == 0 and 8 <= bb <= -(-b // 8) * 8
+                   for bb in cands), cands
 
 
 def test_sweep_skips_failing_candidates():
     calls = []
 
-    def run(bb, bd):
+    def run(bb, bh):
         def thunk():
-            calls.append((bb, bd))
-            if bb == 2:
+            calls.append((bb, bh))
+            if bb == 16:
                 raise ValueError("backend rejected tiling")
             import jax.numpy as jnp
             return jnp.zeros(())
         return thunk
 
-    res = autotune.sweep(run, [(1, 8), (2, 8), (4, 8)], iters=1)
-    assert res["best"] in {(1, 8), (4, 8)}
+    res = autotune.sweep(run, [(8, 128), (16, 128), (32, 128)], iters=1)
+    assert res["best"] in {(8, 128), (32, 128)}
     failed = [r for r in res["sweep"] if r["us"] is None]
-    assert [(r["block_b"], r["block_d"]) for r in failed] == [(2, 8)]
+    assert [r["blocks"] for r in failed] == [[16, 128]]
 
 
 def test_kernel_bench_record_validates(cache):

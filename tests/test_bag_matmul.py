@@ -180,7 +180,8 @@ def test_sharded_bag_matmul_mesh1_matches_host():
     from repro.dist.packed import shard_packed, sharded_bag_matmul
 
     packed = _packed(seed=4)
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = jax.make_mesh((1,), ("model",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     sp = shard_packed(packed, mesh)
     rng = np.random.default_rng(19)
     b, f, h = 8, 4, 6
@@ -225,7 +226,8 @@ cfg = FQuantConfig(stochastic=False)
 st = st._replace(table=qs.snap(st.table, qs.current_tiers(st, cfg), cfg))
 packed = pack(st, cfg)
 
-mesh = jax.make_mesh((4,), ("model",))
+mesh = jax.make_mesh((4,), ("model",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 sp = shard_packed(packed, mesh)
 rng = np.random.default_rng(23)
 b, f, h = 8, 4, 6

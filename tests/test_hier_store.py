@@ -449,7 +449,8 @@ st = st._replace(priority=jnp.asarray((rng.pareto(1.2, V) * 20)
                                       .astype(np.float32)))
 st = st._replace(table=qs.snap(st.table, qs.current_tiers(st, CFG), CFG))
 packed = pack(st, CFG)
-mesh = jax.make_mesh((4,), ("model",))
+mesh = jax.make_mesh((4,), ("model",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 b = packed.nbytes() // 16
 hier = build_hier(st, CFG, HierConfig(
     hbm_budget_bytes=b, host_budget_bytes=b, rows_per_shard=16,

@@ -21,7 +21,7 @@ from repro.kernels.dequant_bag.kernel import (
 from repro.kernels.dequant_bag.ops import (
     packed_bag_lookup,
     packed_lookup_fused,
-    pick_block_sizes,
+    pick_block_b,
 )
 from repro.kernels.dequant_bag.ref import dequant_bag_ref
 from repro.kernels.rowwise_quant.kernel import quantize_rowwise_pallas
@@ -88,7 +88,7 @@ def _bag_case(v, d, b, k, seed=0, payload_dtype=jnp.int8, zero_frac=0.3):
 
 
 def test_dequant_bag_tiled_bit_identical_to_rowgrid():
-    """The tiled (B_block, D_block) kernel accumulates each bag in the
+    """The tiled (B_block) kernel accumulates each bag in the
     same k order as the pre-refactor (B, K)-grid kernel -> bit-equal."""
     for shape in [(64, 128, 8, 5), (32, 64, 16, 1), (128, 256, 7, 9),
                   (50, 24, 3, 4), (40, 48, 5, 3)]:
@@ -102,13 +102,12 @@ def test_dequant_bag_tiled_bit_identical_to_rowgrid():
 
 def test_dequant_bag_block_size_invariance_bitwise():
     """Block geometry changes DMA batching, never accumulation order:
-    any (block_b, block_d) choice gives bit-identical bags."""
-    payload, scales, idx, w = _bag_case(80, 96, 11, 6)
-    base = dequant_bag_pallas(payload, scales, idx, w,
-                              block_b=1, block_d=96)
-    for bb, bd in [(2, 48), (4, 96), (8, 32), (16, 96), (3, 16)]:
-        out = dequant_bag_pallas(payload, scales, idx, w,
-                                 block_b=bb, block_d=bd)
+    any block_b (one grid block, several, a ragged last one) gives
+    bit-identical bags."""
+    payload, scales, idx, w = _bag_case(80, 96, 37, 6)
+    base = dequant_bag_pallas(payload, scales, idx, w, block_b=8)
+    for bb in (16, 24, 32, 40, 64):
+        out = dequant_bag_pallas(payload, scales, idx, w, block_b=bb)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(base))
 
 
@@ -143,15 +142,16 @@ def test_dequant_bag_k1_bit_identical_to_ref():
 
 
 def test_dequant_bag_d_not_multiple_of_block():
-    """Explicit block_d that does not divide D (and one larger than D)
-    exercises the column-padding correctness path."""
-    payload, scales, idx, w = _bag_case(32, 20, 4, 3)
-    ref = dequant_bag_pallas(payload, scales, idx, w,
-                             block_b=2, block_d=20)
-    for bd in (7, 13, 32):
-        out = dequant_bag_pallas(payload, scales, idx, w,
-                                 block_b=2, block_d=bd)
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+    """Row widths that do not divide the 128 lanes (padded into the
+    lane-dense view) match the oracle for every payload dtype."""
+    for d in (7, 13, 20, 100, 130):
+        for dt in (jnp.int8, jnp.bfloat16, jnp.float32):
+            payload, scales, idx, w = _bag_case(32, d, 9, 3,
+                                                payload_dtype=dt)
+            out = dequant_bag_pallas(payload, scales, idx, w)
+            ref = dequant_bag_ref(payload, scales, idx, w)
+            np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                       rtol=1e-5, atol=1e-6)
 
 
 @settings(max_examples=16, deadline=None)
@@ -171,39 +171,42 @@ def test_dequant_bag_tiled_property_vs_ref(b, k, d, seed):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(rowgrid))
 
 
-def _working_set(bb, bd, k, itemsize):
-    # mirrors ops._auto_block_b: fp32 out tile + landing ring +
-    # gathered scale/weight blocks
+def _working_set(bb, d, k, itemsize):
+    # mirrors ops._auto_block_b: double-buffered fp32 out tile (whole
+    # lanes) + landing ring of whole lane-dense rows (one packed tile
+    # per row for narrow dtypes)
+    from repro.kernels import rows
     from repro.kernels.dequant_bag.ops import resolve_nbuf
+    dp, r = rows.row_layout(d)
+    lanes = -(-dp // 128) * 128
+    g = 8 * max(1, 4 // itemsize)
     nbuf = resolve_nbuf(bb * k)
-    return bb * bd * 4 + nbuf * bd * itemsize + 2 * bb * k * 4
+    return 2 * bb * lanes * 4 + nbuf * g * r * dp * itemsize
 
 
 def test_pick_block_sizes_properties():
     for b, k, d, itemsize in [(1, 1, 1, 1), (256, 8, 512, 1),
                               (1024, 64, 384, 2), (7, 3, 250, 4),
                               (64, 1, 2048, 4)]:
-        bb, bd = pick_block_sizes(b, k, d, itemsize)
-        assert 1 <= bb <= max(1, b)
-        assert d % bd == 0, (d, bd)
-        assert bd <= max(d, 1)
-        # working set stays under the VMEM budget (or is minimal bb=1)
-        assert bb == 1 or _working_set(bb, bd, k, itemsize) <= 2 << 20
+        bb = pick_block_b(b, k, d, itemsize)
+        # whole 8-row output tiles, never past the 8-padded batch
+        assert bb % 8 == 0 and 8 <= bb <= max(8, -(-b // 8) * 8)
+        # working set stays under the VMEM budget (or is minimal bb=8)
+        assert bb == 8 or _working_set(bb, d, k, itemsize) <= 2 << 20
+        # and the SMEM slot cap
+        assert bb == 8 or bb * k <= 8192
 
 
 def test_pick_block_sizes_awkward_dims():
-    """Prime/odd D > 512 has no 128-aligned divisor; the picker must
-    return a 128-aligned non-divisor (edge-padded in-kernel) instead of
-    serializing the D axis with block_d=1."""
+    """Prime/odd D > 512 pads to whole lanes: the bag block is sized
+    against the padded row, so wider rows never get a larger block,
+    and the kernel runs correctly end to end."""
+    picks = [pick_block_b(4096, 4, d, 4) for d in (64, 521, 1013, 2049)]
+    assert all(bb % 8 == 0 for bb in picks)
+    assert picks == sorted(picks, reverse=True), picks
     for d in (521, 1013, 999, 2049):
-        bb, bd = pick_block_sizes(64, 4, d, 1)
-        assert bd % 128 == 0 and bd <= 512, (d, bd)
-        assert bd > 1
-    # small awkward dims keep the exact-divisor behaviour (no padding)
-    for d in (250, 96, 7):
-        _, bd = pick_block_sizes(64, 4, d, 1)
-        assert d % bd == 0
-    # and the non-divisor pick still runs correctly end to end
+        bb = pick_block_b(4096, 4, d, 4)
+        assert bb == 8 or _working_set(bb, d, 4, 4) <= 2 << 20
     payload, scales, idx, w = _bag_case(32, 521, 4, 3)
     out = dequant_bag_pallas(payload, scales, idx, w)
     ref = dequant_bag_ref(payload, scales, idx, w)
@@ -212,34 +215,28 @@ def test_pick_block_sizes_awkward_dims():
 
 
 def test_pick_block_sizes_env_override(monkeypatch):
-    base = pick_block_sizes(64, 4, 128, 1)
-    monkeypatch.setenv("REPRO_DEQUANT_BLOCK_B", "3")
-    monkeypatch.setenv("REPRO_DEQUANT_BLOCK_D", "16")
+    base = pick_block_b(64, 4, 128, 1)
+    monkeypatch.setenv("REPRO_DEQUANT_BLOCK_B", "24")
     # env is read per call — overrides apply even after a cached pick
-    assert pick_block_sizes(64, 4, 128, 1) == (3, 16)
-    # overriding D alone re-sizes B against the new D (budget stays
-    # consistent), instead of pairing it with the auto-D's B
+    assert pick_block_b(64, 4, 128, 1) == 24
+    # an override off the 8-row tile rule comes back as the block that
+    # runs, not as asked
+    monkeypatch.setenv("REPRO_DEQUANT_BLOCK_B", "3")
+    assert pick_block_b(64, 4, 128, 1) == 8
     monkeypatch.delenv("REPRO_DEQUANT_BLOCK_B")
-    monkeypatch.setenv("REPRO_DEQUANT_BLOCK_D", "1024")
-    bb, bd = pick_block_sizes(1024, 64, 128, 1)
-    assert bd == 1024
-    assert bb == 1 or _working_set(bb, 1024, 64, 1) <= 2 << 20
-    monkeypatch.delenv("REPRO_DEQUANT_BLOCK_D")
-    assert pick_block_sizes(64, 4, 128, 1) == base
+    assert pick_block_b(64, 4, 128, 1) == base
 
 
-def test_resolve_block_sizes_call_arg_overrides():
-    from repro.kernels.dequant_bag.ops import resolve_block_sizes
-    # pinning D alone re-sizes B against the pinned value — the VMEM
-    # working-set budget holds for call-arg overrides like env overrides
-    bb, bd = resolve_block_sizes(1024, 64, 128, 1, block_d=1024)
-    assert bd == 1024
-    assert bb == 1 or _working_set(bb, 1024, 64, 1) <= 2 << 20
-    bb2, bd2 = resolve_block_sizes(64, 4, 128, 1, block_b=5)
-    assert (bb2, bd2) == (5, 128)
-    for bad in ({"block_b": 0}, {"block_d": -1}):
-        with pytest.raises(ValueError):
-            resolve_block_sizes(8, 2, 16, 1, **bad)
+def test_resolve_block_sizes_call_arg_overrides(monkeypatch):
+    from repro.kernels.dequant_bag.ops import resolve_block_b
+    # an explicit argument beats the env override, and is rounded up
+    # to the tile rule like any other source
+    monkeypatch.setenv("REPRO_DEQUANT_BLOCK_B", "64")
+    assert resolve_block_b(64, 4, 128, 1, block_b=16) == 16
+    assert resolve_block_b(64, 4, 128, 1, block_b=5) == 8
+    assert resolve_block_b(64, 4, 128, 1, block_b=17) == 24
+    with pytest.raises(ValueError):
+        resolve_block_b(8, 2, 16, 1, block_b=0)
 
 
 def test_should_interpret_autodetect_and_overrides(monkeypatch):

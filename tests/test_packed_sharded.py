@@ -98,7 +98,8 @@ def test_sharded_fused_lookup_mesh1_bit_identical():
     from repro.kernels.dequant_bag.ops import packed_bag_lookup
 
     packed = _packed(seed=4)
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = jax.make_mesh((1,), ("model",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     sp = shard_packed(packed, mesh)
     rng = np.random.default_rng(17)
     idx = jnp.asarray(rng.integers(0, packed.vocab, (9, 5))
@@ -137,7 +138,8 @@ cfg = FQuantConfig(stochastic=False)
 st = st._replace(table=qs.snap(st.table, qs.current_tiers(st, cfg), cfg))
 packed = pack(st, cfg)
 
-mesh = jax.make_mesh((4,), ("model",))
+mesh = jax.make_mesh((4,), ("model",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 sp = shard_packed(packed, mesh)
 
 rng = np.random.default_rng(11)

@@ -160,7 +160,8 @@ ds = CriteoSynth(CriteoConfig(
     cardinalities=tuple(int(c) for c in spec.cardinalities),
     num_dense=arch.smoke_num_dense,
     important_fields=spec.num_fields // 2))
-mesh = jax.make_mesh((2,), ("model",))
+mesh = jax.make_mesh((2,), ("model",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 
 def make(m):
     return make_compressed_train_step(

@@ -232,7 +232,8 @@ st = st._replace(priority=jnp.asarray((rng.pareto(1.2, V) * 20)
                                       .astype(np.float32)))
 st = st._replace(table=qs.snap(st.table, qs.current_tiers(st, CFG), CFG))
 
-mesh = jax.make_mesh((4,), ("model",))
+mesh = jax.make_mesh((4,), ("model",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 sp = shard_packed(pack(st, CFG), mesh)
 
 # unshard trims padding back to the packed layout

@@ -70,6 +70,24 @@ def _mirror(server):
     return np.asarray(ps.unpack(server.host_packed))
 
 
+def _record_swaps(server) -> list:
+    """Snapshots of the shadows ``server`` swaps in, in order.  A
+    re-tier queued while a shadow is in flight opens a second shadow
+    that can be built and swapped inside one later op (``drain``), so
+    the oracle reads the snapshot each swap committed rather than the
+    last one the scheduler saw between ops."""
+    snaps = []
+    swap = server._swap
+
+    def _swap():
+        snap = server.shadow.snapshot
+        out = swap()
+        snaps.append(snap)
+        return out
+    server._swap = _swap
+    return snaps
+
+
 def run_flat_schedule(server, ops, rng):
     """Execute one op schedule, asserting the lockstep oracle after
     every op.  ``mirror`` is the unpacked synchronous pack at the last
@@ -79,7 +97,7 @@ def run_flat_schedule(server, ops, rng):
     mirror = _mirror(server)
     np.testing.assert_array_equal(
         mirror, np.asarray(ps.unpack(pack(server.store, CFG))))
-    last_snap = None
+    snaps = _record_swaps(server)
     swaps = 0
     for op in ops:
         pre_swaps = server.stats.swaps
@@ -111,15 +129,13 @@ def run_flat_schedule(server, ops, rng):
             np.testing.assert_array_equal(_mirror(server), mirror)
         if server.stats.swaps > pre_swaps:
             swaps += server.stats.swaps - pre_swaps
-            mirror = np.asarray(ps.unpack(pack(last_snap, CFG)))
+            mirror = np.asarray(ps.unpack(pack(snaps[-1], CFG)))
         np.testing.assert_array_equal(_mirror(server), mirror)
-        if server.shadow is not None:
-            last_snap = server.shadow.snapshot
     pre_swaps = server.stats.swaps
     server.drain_shadow()           # joins the staging thread too
     if server.stats.swaps > pre_swaps:
         swaps += server.stats.swaps - pre_swaps
-        mirror = np.asarray(ps.unpack(pack(last_snap, CFG)))
+        mirror = np.asarray(ps.unpack(pack(snaps[-1], CFG)))
     np.testing.assert_array_equal(_mirror(server), mirror)
     return swaps
 
@@ -290,7 +306,7 @@ def run_hier_schedule(server, ops, rng, store_dir):
     mirror = _hier_mirror(server)
     np.testing.assert_array_equal(
         mirror, np.asarray(ps.unpack(pack(server.store, CFG))))
-    last_snap = None
+    snaps = _record_swaps(server)
     for op in ops:
         pre_swaps = server.stats.swaps
         if op == "serve":
@@ -319,14 +335,12 @@ def run_hier_schedule(server, ops, rng, store_dir):
                                               ".tmp_hier_*"),
                                  recursive=True)
         if server.stats.swaps > pre_swaps:
-            mirror = np.asarray(ps.unpack(pack(last_snap, CFG)))
+            mirror = np.asarray(ps.unpack(pack(snaps[-1], CFG)))
         np.testing.assert_array_equal(_hier_mirror(server), mirror)
-        if server.shadow is not None:
-            last_snap = server.shadow.snapshot
     pre_swaps = server.stats.swaps
     server.drain_shadow()
     if server.stats.swaps > pre_swaps:
-        mirror = np.asarray(ps.unpack(pack(last_snap, CFG)))
+        mirror = np.asarray(ps.unpack(pack(snaps[-1], CFG)))
     np.testing.assert_array_equal(_hier_mirror(server), mirror)
 
 
@@ -388,7 +402,8 @@ except ImportError:
 import numpy as np, jax
 from test_shadow_swap import OPS, _flat_server, run_flat_schedule
 
-mesh = jax.make_mesh((4,), ("model",))
+mesh = jax.make_mesh((4,), ("model",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 rng = np.random.default_rng(7)
 server = _flat_server(seed=1, mesh=mesh)
 ops = [OPS[i] for i in rng.integers(0, len(OPS), 30)]

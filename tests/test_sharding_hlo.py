@@ -51,7 +51,8 @@ def test_zero1_specs_add_data_axis():
 
 
 def test_validate_divisibility_flags_bad_dims():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     params = {"w": jax.ShapeDtypeStruct((7, 3), jnp.float32)}
     # trivial 1x1 mesh: everything divides
     assert sh.validate_divisibility(params, {"w": P("data", None)},
@@ -116,7 +117,8 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
 from repro.dist.collectives import split_kv_decode_attention
-mesh = jax.make_mesh((4,), ("model",))
+mesh = jax.make_mesh((4,), ("model",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 b, s, h, d = 2, 32, 4, 16
 q = jax.random.normal(jax.random.PRNGKey(0), (b, h, d))
 k = jax.random.normal(jax.random.PRNGKey(1), (b, s, h, d))

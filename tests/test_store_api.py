@@ -275,7 +275,8 @@ rng = np.random.default_rng(9)
 pool = jnp.asarray(rng.standard_normal((S, Z)).astype(np.float32))
 idx = jnp.asarray(rng.integers(0, V, (6, 4)), jnp.int32)
 cot = jnp.asarray(rng.standard_normal((6, 4, D)).astype(np.float32))
-mesh = jax.make_mesh((4,), ("model",))
+mesh = jax.make_mesh((4,), ("model",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 
 def dense(p):
     ids = jnp.arange(V, dtype=jnp.int32)

@@ -458,13 +458,15 @@ def _validate_kernel(rec: dict) -> list[str]:
                               f"with timings ({ua / um:.4f})")
         for kk in ("block_analytic", "block_measured"):
             blk = e.get(kk)
+            # (block_b,) for the bag kernels, (block_b, block_h) for
+            # bag_matmul
             if isinstance(blk, list) and not (
-                    len(blk) == 2
+                    len(blk) in (1, 2)
                     and all(isinstance(x, numbers.Integral)
                             and not isinstance(x, bool) and x >= 1
                             for x in blk)):
-                errors.append(f"sweep[{i}]: {kk} must be two ints "
-                              f">= 1, got {blk!r}")
+                errors.append(f"sweep[{i}]: {kk} must be one or two "
+                              f"ints >= 1, got {blk!r}")
         for kk in ("bytes_moved", "achieved_gbs", "peak_fraction"):
             v = e.get(kk)
             if _is_num(v) and v <= 0:
