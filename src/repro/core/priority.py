@@ -60,6 +60,7 @@ def batch_counts(indices: Array, labels: Array, vocab: int,
     return c_pos, c_neg
 
 
+@jax.named_scope("priority_update")
 def priority_update(w: Array, c_pos: Array, c_neg: Array,
                     cfg: PriorityConfig = PriorityConfig()) -> Array:
     """One Eq. 7 step.  w, c_pos, c_neg: (vocab,) float32."""
@@ -74,6 +75,7 @@ def priority_update_from_batch(w: Array, indices: Array, labels: Array,
     return priority_update(w, c_pos, c_neg, cfg)
 
 
+@jax.named_scope("access_counts")
 def access_counts(indices: Array, vocab: int,
                   valid: Array | None = None) -> Array:
     """Label-free per-row hit counts for a serving batch.

@@ -161,17 +161,18 @@ def packed_bag_lookup(packed: PackedStore, indices: Array,
 
     ones32 = jnp.ones((packed.payload32.shape[0],), jnp.float32)
     out = jnp.zeros((indices.shape[0], packed.dim), jnp.float32)
-    for t, payload, scales in (
-            (0, packed.payload8, packed.scale8),
-            (1, packed.payload16, packed.scale16),
-            (2, packed.payload32, ones32)):
-        w = (tier == t).astype(jnp.float32)
-        if weights is not None:
-            w = w * weights
-        li = jnp.clip(loc, 0, payload.shape[0] - 1)
-        out = out + dequant_bag_tpu(payload, scales, li, w,
-                                    use_pallas=use_pallas,
-                                    interpret=interpret)
+    for t, name, payload, scales in (
+            (0, "int8", packed.payload8, packed.scale8),
+            (1, "half", packed.payload16, packed.scale16),
+            (2, "fp32", packed.payload32, ones32)):
+        with jax.named_scope(f"gather_{name}"):
+            w = (tier == t).astype(jnp.float32)
+            if weights is not None:
+                w = w * weights
+            li = jnp.clip(loc, 0, payload.shape[0] - 1)
+            out = out + dequant_bag_tpu(payload, scales, li, w,
+                                        use_pallas=use_pallas,
+                                        interpret=interpret)
     return out
 
 

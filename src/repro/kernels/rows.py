@@ -73,6 +73,7 @@ def phys_rows(v: int, d: int, dtype) -> int:
     return -(-(-(-v // r)) // g) * g
 
 
+@jax.named_scope("lane_dense")
 def lane_dense(table: Array) -> Array:
     """(V, D) -> the (P, r * dp) physical view read by the kernels.
     A no-op for 32-bit tables whose D is a multiple of 128 and whose V
@@ -92,6 +93,7 @@ def lane_dense(table: Array) -> Array:
         p, r * dp)
 
 
+@jax.named_scope("from_lane_dense")
 def from_lane_dense(phys: Array, v: int, d: int) -> Array:
     """Inverse of ``lane_dense``: (P, r * dp) -> (V, D)."""
     dp, r = row_layout(d)

@@ -152,23 +152,21 @@ def _main() -> None:
         def serve_fn(mb):
             r = counter["b"]
             counter["b"] += 1
-            with obs.span("serve.synth"):
-                b = {"indices": jnp.asarray(mb.indices),
-                     "labels": jnp.zeros((mb.indices.shape[0],))}
-                if num_dense:
-                    rr = np.random.default_rng(20_000 + r)
-                    b["dense"] = jnp.asarray(rr.standard_normal(
-                        (mb.indices.shape[0], num_dense))
-                        .astype(np.float32))
-                valid = jnp.asarray(mb.valid)
-                last["a"] = (b, valid)
+            b = {"indices": jnp.asarray(mb.indices),
+                 "labels": jnp.zeros((mb.indices.shape[0],))}
+            if num_dense:
+                rr = np.random.default_rng(20_000 + r)
+                b["dense"] = jnp.asarray(rr.standard_normal(
+                    (mb.indices.shape[0], num_dense))
+                    .astype(np.float32))
+            valid = jnp.asarray(mb.valid)
+            last["a"] = (b, valid)
             with obs.span("serve.lookup"):
                 out, hits, gidx = fwd(server.packed, server.cache,
                                       params, b, valid)
                 jax.block_until_ready(out)
-            with obs.span("serve.combine"):
-                server.observe(gidx, int(hits),
-                               valid=mb.valid[:, None], count=mb.count)
+            server.observe(gidx, int(hits),
+                           valid=mb.valid[:, None], count=mb.count)
             return out
 
         return Replica(

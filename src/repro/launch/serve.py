@@ -61,27 +61,30 @@ def build_serving_store(spec, table, seed: int = 0):
     """The QAT store the drivers serve: a zipf-like priority profile
     drawn from ``seed``, Eq. 8 thresholds planned for a 50% memory
     budget, and every row snapped to its tier.  Returns (store, cfg);
-    ``OnlineServer`` / ``pack`` turn it into the packed store."""
+    ``OnlineServer`` / ``pack`` turn it into the packed store.  Timed
+    as ``store.plan`` (profile and thresholds) and ``store.snap``."""
     import jax.numpy as jnp
 
     from repro.core import FQuantConfig
     from repro.core import qat_store as qs
     from repro.core.tiers import plan_thresholds_for_ratio
 
-    rng = np.random.default_rng(seed)
-    pri = jnp.asarray((rng.pareto(1.2, spec.total_rows) * 10)
-                      .astype(np.float32))
-    cfg = FQuantConfig(
-        tiers=plan_thresholds_for_ratio(pri, spec.dim, 0.5),
-        stochastic=False)
-    store = qs.QATStore(table, pri)
-    tiers = qs.current_tiers(store, cfg)
-    # snap in row chunks: each eager step of snap holds a full-size
-    # buffer, too many at once for one chip at published widths
-    chunk = 1 << 20
-    store = store._replace(table=jnp.concatenate(
-        [qs.snap(table[i:i + chunk], tiers[i:i + chunk], cfg)
-         for i in range(0, table.shape[0], chunk)]))
+    with obs.timeblock("store.plan"):
+        rng = np.random.default_rng(seed)
+        pri = jnp.asarray((rng.pareto(1.2, spec.total_rows) * 10)
+                          .astype(np.float32))
+        cfg = FQuantConfig(
+            tiers=plan_thresholds_for_ratio(pri, spec.dim, 0.5),
+            stochastic=False)
+    with obs.timeblock("store.snap") as tb:
+        store = qs.QATStore(table, pri)
+        tiers = qs.current_tiers(store, cfg)
+        # snap in row chunks: each eager step of snap holds a full-size
+        # buffer, too many at once for one chip at published widths
+        chunk = 1 << 20
+        store = store._replace(table=tb.sync(jnp.concatenate(
+            [qs.snap(table[i:i + chunk], tiers[i:i + chunk], cfg)
+             for i in range(0, table.shape[0], chunk)])))
     return store, cfg
 
 

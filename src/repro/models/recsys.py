@@ -93,6 +93,7 @@ def make_dlrm(cfg: DLRMConfig) -> Model:
         return E.field_lookup(params["embed_table"], batch["indices"], spec,
                               field_mask)
 
+    @jax.named_scope("head")
     def head(params, emb, batch):
         dense = L.mlp(params["net"]["bot"], batch["dense"],
                       final_act=True)                      # (B, D)
@@ -151,6 +152,7 @@ def make_wide_deep(cfg: WideDeepConfig) -> Model:
         return E.field_lookup(params["embed_table"], batch["indices"], spec,
                               field_mask)
 
+    @jax.named_scope("head")
     def head(params, emb, batch):
         b = emb.shape[0]
         wide_spec = E.FieldSpec(spec.cardinalities, 1)
@@ -242,6 +244,7 @@ def make_xdeepfm(cfg: XDeepFMConfig) -> Model:
         return E.field_lookup(params["embed_table"], batch["indices"], spec,
                               field_mask)
 
+    @jax.named_scope("head")
     def head(params, emb, batch):
         b = emb.shape[0]
         x0 = emb

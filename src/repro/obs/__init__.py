@@ -13,7 +13,10 @@ JSONL (``launch/serve.py --metrics-out`` / ``launch/pipeline.py
   trace      ``span("stage")`` nestable timed stages and
              ``timeblock``, the one wall-clock idiom shared by the
              serve, train and bench loops (``tb.sync(x)`` =
-             ``jax.block_until_ready`` inside the clock)
+             ``jax.block_until_ready`` inside the clock); both write
+             profiler annotations while a trace runs and land in the
+             span log (``span_log()``, ``time.perf_counter_ns``) with
+             every compile and compile-cache load
   export     ``metrics_snapshot/v1`` snapshots, statsd line protocol,
              and the periodic JSONL sink driven by ``tick()``
              (``close_sink()`` on loop exit lands the final partial
@@ -57,8 +60,10 @@ from repro.obs.registry import (  # noqa: F401
 )
 from repro.obs.trace import (  # noqa: F401
     Span,
+    SpanLog,
     Timeblock,
     current_path,
     span,
+    span_log,
     timeblock,
 )

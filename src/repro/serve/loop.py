@@ -48,8 +48,9 @@ from repro.serve.online import OnlineServer
 # the drivers when metrics are on, so every snapshot carries the full
 # per-phase histogram catalog even for phases that never fired (e.g.
 # stage/migrate when serving a fully resident store)
-SERVE_PHASES = ("serve.request", "serve.synth", "serve.stage",
-                "serve.lookup", "serve.combine", "serve.retier",
+SERVE_PHASES = ("serve.request", "serve.stage", "serve.lookup",
+                "serve.fold", "serve.fold.mask", "serve.fold.priority",
+                "serve.fold.retier", "serve.retier",
                 "serve.shadow.plan", "serve.shadow.chunk",
                 "serve.shadow.build", "serve.shadow.stage",
                 "serve.shadow.verify", "serve.shadow.warmup",
@@ -392,19 +393,17 @@ def serve_forward_loop(server: OnlineServer, model, spec, params, *,
     def serve_fn(idx: np.ndarray):
         r = counter["r"]
         counter["r"] += 1
-        with obs.span("serve.synth"):
-            b = {"indices": jnp.asarray(idx),
-                 "labels": jnp.zeros((idx.shape[0],))}
-            if num_dense:
-                rr = np.random.default_rng(10_000 + r)
-                b["dense"] = jnp.asarray(rr.standard_normal(
-                    (idx.shape[0], num_dense)).astype(np.float32))
-            last["b"] = b
+        b = {"indices": jnp.asarray(idx),
+             "labels": jnp.zeros((idx.shape[0],))}
+        if num_dense:
+            rr = np.random.default_rng(10_000 + r)
+            b["dense"] = jnp.asarray(rr.standard_normal(
+                (idx.shape[0], num_dense)).astype(np.float32))
+        last["b"] = b
         with obs.span("serve.lookup"):
             out, hits, gidx = fwd(server.packed, server.cache, params, b)
             jax.block_until_ready(out)
-        with obs.span("serve.combine"):
-            server.observe(gidx, int(hits))
+        server.observe(gidx, int(hits))
         return out
 
     cards = np.asarray(spec.cardinalities, np.int64)
@@ -469,23 +468,21 @@ def serve_forward_microbatched(server: OnlineServer, model, spec,
     def serve_fn(mb: MicroBatch):
         r = counter["b"]
         counter["b"] += 1
-        with obs.span("serve.synth"):
-            b = {"indices": jnp.asarray(mb.indices),
-                 "labels": jnp.zeros((mb.indices.shape[0],))}
-            if num_dense:
-                rr = np.random.default_rng(20_000 + r)
-                b["dense"] = jnp.asarray(rr.standard_normal(
-                    (mb.indices.shape[0], num_dense)).astype(np.float32))
-            valid = jnp.asarray(mb.valid)
-            last["a"] = (b, valid)
+        b = {"indices": jnp.asarray(mb.indices),
+             "labels": jnp.zeros((mb.indices.shape[0],))}
+        if num_dense:
+            rr = np.random.default_rng(20_000 + r)
+            b["dense"] = jnp.asarray(rr.standard_normal(
+                (mb.indices.shape[0], num_dense)).astype(np.float32))
+        valid = jnp.asarray(mb.valid)
+        last["a"] = (b, valid)
         with obs.span("serve.lookup"):
             args = (server.packed, server.cache, params, b, valid)
             last["call"] = args
             out, hits, gidx = fwd(*args)
             jax.block_until_ready(out)
-        with obs.span("serve.combine"):
-            server.observe(gidx, int(hits), valid=mb.valid[:, None],
-                           count=mb.count)
+        server.observe(gidx, int(hits), valid=mb.valid[:, None],
+                       count=mb.count)
         return out
 
     cards = np.asarray(spec.cardinalities, np.int64)
@@ -593,13 +590,12 @@ def _serve_forward_staged(server: OnlineServer, model, spec, params, *,
                     if server.cache_mask is not None else None)
             sb = backend.stage_host(g, skip=skip,
                                     valid=mb.valid[:, None])
-        with obs.span("serve.synth"):
-            b = {"indices": jnp.asarray(mb.indices),
-                 "labels": jnp.zeros((mb.indices.shape[0],))}
-            if num_dense:
-                rr = np.random.default_rng(20_000 + r)
-                b["dense"] = jnp.asarray(rr.standard_normal(
-                    (mb.indices.shape[0], num_dense)).astype(np.float32))
+        b = {"indices": jnp.asarray(mb.indices),
+             "labels": jnp.zeros((mb.indices.shape[0],))}
+        if num_dense:
+            rr = np.random.default_rng(20_000 + r)
+            b["dense"] = jnp.asarray(rr.standard_normal(
+                (mb.indices.shape[0], num_dense)).astype(np.float32))
         with obs.span("serve.lookup"):
             valid = jnp.asarray(mb.valid)
             last["a"] = (b, valid, sb.hot_local, sb.stage_slot,
@@ -608,9 +604,8 @@ def _serve_forward_staged(server: OnlineServer, model, spec, params, *,
                                   b, valid, sb.hot_local, sb.stage_slot,
                                   sb.staging)
             jax.block_until_ready(out)
-        with obs.span("serve.combine"):
-            server.observe(gidx, int(hits), valid=mb.valid[:, None],
-                           count=mb.count)
+        server.observe(gidx, int(hits), valid=mb.valid[:, None],
+                       count=mb.count)
         return out
 
     cards = np.asarray(spec.cardinalities, np.int64)

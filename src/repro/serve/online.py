@@ -250,40 +250,51 @@ class OnlineServer:
         store cannot re-tier mid-forward), so with
         ``serve_batch > retier_every`` the adaptation rate is once per
         micro-batch, not once per boundary.
+
+        Traced as span ``serve.fold``, keyed by the request count
+        before the batch, over ``serve.fold.mask`` (valid-mask build
+        and upload), ``serve.fold.priority`` (the eager Eq. 7 chain)
+        and ``serve.fold.retier`` (boundary check, re-tier, shadow
+        tick).
         """
         import jax.numpy as jnp
-        before = self.stats.requests
-        self.stats.requests += count
-        if valid is None:
-            n_lookups = int(np.prod(np.shape(indices)))
-            vmask = None
-        else:
-            # count host-side (valid is the batcher's numpy mask) — no
-            # device round-trip inside the timed serving path
-            vnp = np.broadcast_to(np.asarray(valid, bool),
-                                  np.shape(indices))
-            n_lookups = int(vnp.sum())
-            vmask = jnp.asarray(vnp)
-        self.stats.lookups += n_lookups
-        if hits is not None:
-            self.stats.hits += int(hits)
-        if obs.enabled():
-            obs.inc("serve.requests", count)
-            obs.inc("serve.lookups", n_lookups)
+        with obs.span("serve.fold", key=self.stats.requests):
+            before = self.stats.requests
+            self.stats.requests += count
+            with obs.span("serve.fold.mask"):
+                if valid is None:
+                    n_lookups = int(np.prod(np.shape(indices)))
+                    vmask = None
+                else:
+                    # count host-side (valid is the batcher's numpy
+                    # mask) — no device round-trip inside the timed
+                    # serving path
+                    vnp = np.broadcast_to(np.asarray(valid, bool),
+                                          np.shape(indices))
+                    n_lookups = int(vnp.sum())
+                    vmask = jnp.asarray(vnp)
+            self.stats.lookups += n_lookups
             if hits is not None:
-                obs.inc("serve.cache.hits", int(hits))
-            obs.gauge("serve.cache.hit_rate", self.stats.hit_rate)
-        pcfg = self.online.priority or self._default_priority_cfg()
-        self.backend.fold_priority(indices, pcfg, valid=vmask)
-        if self.online.retier_every:
-            re = self.online.retier_every
-            if self.stats.requests // re > before // re:
-                if not self.online.retier_async:
-                    return self.retier()
-                self._retier_pending = True
-        if self.online.retier_async:
-            return self._shadow_tick(count)
-        return False
+                self.stats.hits += int(hits)
+            if obs.enabled():
+                obs.inc("serve.requests", count)
+                obs.inc("serve.lookups", n_lookups)
+                if hits is not None:
+                    obs.inc("serve.cache.hits", int(hits))
+                obs.gauge("serve.cache.hit_rate", self.stats.hit_rate)
+            pcfg = self.online.priority or self._default_priority_cfg()
+            with obs.span("serve.fold.priority"):
+                self.backend.fold_priority(indices, pcfg, valid=vmask)
+            with obs.span("serve.fold.retier"):
+                if self.online.retier_every:
+                    re = self.online.retier_every
+                    if self.stats.requests // re > before // re:
+                        if not self.online.retier_async:
+                            return self.retier()
+                        self._retier_pending = True
+                if self.online.retier_async:
+                    return self._shadow_tick(count)
+                return False
 
     def _default_priority_cfg(self) -> PriorityConfig:
         cfg = self.backend.cfg

@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.priority import PriorityConfig, serve_update
 
 Array = jax.Array
@@ -92,8 +93,10 @@ class PackedBackend:
         self.mesh = mesh
         self.axis = axis
         self.hier = None
-        self.host_packed = (pack(store, cfg) if host_packed is None
-                            else host_packed)
+        if host_packed is None:
+            with obs.timeblock("store.pack"):
+                host_packed = pack(store, cfg)
+        self.host_packed = host_packed
         self.device_store = None
         self.place()
 
@@ -126,8 +129,9 @@ class PackedBackend:
 
     def place(self) -> None:
         from repro.dist.packed import place_packed
-        self.device_store = place_packed(self.host_packed, self.mesh,
-                                         self.axis)
+        with obs.timeblock("store.place"):
+            self.device_store = place_packed(self.host_packed, self.mesh,
+                                             self.axis)
 
     def lookup_fn(self) -> Callable:
         if self.mesh is None:

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import obs  # noqa: F401  (compile listener before any compile)
 from repro.core.qat_store import FQuantConfig
 from repro.data.criteo import CriteoConfig, CriteoSynth
 from repro.models import embedding as E

@@ -311,13 +311,15 @@ def make_compressed_train_step(loss_from_emb: Callable,
 
         loss, (g_dense, g_emb) = jax.value_and_grad(
             head_loss, argnums=(0, 1))(dense, emb)
-        (g_table,) = emb_vjp(g_emb)                    # scatter kernel
+        with jax.named_scope("table_grad"):            # dense (V, D)
+            (g_table,) = emb_vjp(g_emb)                # scatter kernel
 
         # ---- row-wise adagrad on the table (touched rows only: the
         # scatter emits exact zeros for untouched rows) ---------------
         dense_opt_state, accum_sq = state.opt
-        table, accum_sq = opt_lib.rowwise_adagrad_table_update(
-            table, accum_sq, g_table, lr, step=state.step, eps=eps)
+        with jax.named_scope("adagrad"):
+            table, accum_sq = opt_lib.rowwise_adagrad_table_update(
+                table, accum_sq, g_table, lr, step=state.step, eps=eps)
 
         # ---- dense params -------------------------------------------
         upd, dense_opt_state = dense_optimizer.update(
@@ -333,9 +335,10 @@ def make_compressed_train_step(loss_from_emb: Callable,
                 priority, gidx, labels_fn(batch), pcfg)
         elif fq_cfg is not None:
             store = qat_store.QATStore(table=table, priority=priority)
-            store = qat_store.post_step_sparse(
-                store, gidx, labels_fn(batch), fq_cfg,
-                seed=state.step.astype(jnp.uint32))
+            with jax.named_scope("snap"):
+                store = qat_store.post_step_sparse(
+                    store, gidx, labels_fn(batch), fq_cfg,
+                    seed=state.step.astype(jnp.uint32))
             table, priority = store.table, store.priority
 
         # ---- in-training Taylor + access accumulation ---------------

@@ -1,10 +1,17 @@
 """repro.obs: histogram percentile accuracy, exact cross-shard merge,
-span nesting/exception safety, the disabled-mode zero-cost guard, and
-the metrics_snapshot/v1 export contract."""
+span nesting/exception safety, the disabled-mode zero-cost guard, the
+metrics_snapshot/v1 export contract, and the span log: profiler
+annotations, parents and keys, compile counters, the serve fold."""
 
+import glob
 import importlib.util
 import json
+import os
 import pathlib
+import subprocess
+import sys
+import time
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -15,7 +22,9 @@ from repro import obs
 from repro.core import FQuantConfig
 from repro.core import qat_store as qs
 from repro.core.tiers import TierConfig
+from repro.obs import trace as obs_trace
 from repro.obs.registry import NUM_BUCKETS, Histogram, Registry
+from repro.obs.trace import SPAN_LOG_SIZE
 from repro.serve import OnlineConfig, OnlineServer
 
 _SCHEMA_TOOL = (pathlib.Path(__file__).resolve().parents[1]
@@ -291,3 +300,212 @@ def test_serving_bit_identical_with_metrics_on(tmp_path):
     recs = [json.loads(ln) for ln in path.read_text().splitlines()]
     assert recs and all(
         check_bench_schema.validate(r) == [] for r in recs)
+
+
+# -- span log: profiler annotations, parents and keys, compiles --------
+
+def _logged(name, since=0):
+    return [e for e in obs.span_log().spans
+            if e[0] == name and e[3] >= since]
+
+
+def test_span_log_records_only_when_enabled_or_profiling(tmp_path):
+    with obs.span("log.off"):
+        pass
+    assert not _logged("log.off")              # untraced, disabled
+
+    obs.enable()
+    with obs.span("log.enabled"):
+        pass
+    assert len(_logged("log.enabled")) == 1
+
+    obs.disable()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.span("log.profiling") as sp:
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    [(_, parent, key, t0, t1)] = _logged("log.profiling")
+    assert parent is None and key is None
+    assert t1 - t0 == pytest.approx(sp.seconds * 1e9, abs=1)
+    # the log, not the disabled registry
+    assert "log.profiling_us" not in obs.get_registry().histograms
+
+
+def test_timeblock_always_records():
+    t = time.perf_counter_ns()
+    with obs.timeblock("log.tb") as tb:
+        pass
+    tb2 = obs.timeblock("log.tb").start()
+    tb2.stop()
+    got = _logged("log.tb", since=t)
+    assert len(got) == 2 and not obs.enabled()
+    assert got[0][4] - got[0][3] == pytest.approx(tb.seconds * 1e9, abs=1)
+    with obs.timeblock():                      # unnamed: a clock only
+        pass
+    assert all(e[0] is not None for e in obs.span_log().spans)
+
+
+def test_span_log_bounded_with_parents_keys_and_exceptions():
+    obs.enable()
+    t = time.perf_counter_ns()
+    with obs.timeblock("log.outer"):
+        with obs.span("log.mid", key=7):
+            with obs.span("log.inner"):
+                pass
+        with pytest.raises(RuntimeError):
+            with obs.span("log.boom", key=9):
+                raise RuntimeError("x")
+    assert obs.current_path() == ""
+    [outer] = _logged("log.outer", t)
+    [mid] = _logged("log.mid", t)
+    [inner] = _logged("log.inner", t)
+    [boom] = _logged("log.boom", t)
+    assert outer[1:3] == (None, None)
+    assert mid[1:3] == ("log.outer", 7)
+    assert inner[1:3] == ("log.mid", 7)        # key inherited
+    assert boom[1:3] == ("log.outer", 9)       # recorded despite raise
+    assert outer[3] <= mid[3] <= inner[3] <= inner[4] <= mid[4] \
+        <= boom[3] <= boom[4] <= outer[4]
+
+    for _ in range(SPAN_LOG_SIZE + 10):
+        with obs.timeblock("log.fill"):
+            pass
+    spans = obs.span_log().spans
+    assert len(spans) == SPAN_LOG_SIZE
+    assert spans[-1][0] == "log.fill" and not _logged("log.outer", t)
+
+
+def test_untraced_span_costs_two_flag_checks(monkeypatch):
+    """Disabled registry, no profiler: ``span`` reads the registry's
+    flag and the profiler's once each, then hands out the shared no-op
+    without reading a clock, building a span or touching the log."""
+    class Refuse:
+        def __init__(self, what):
+            self.what = what
+
+        def __getattr__(self, name):
+            raise AssertionError(f"{self.what}.{name} used")
+
+        def __call__(self, *args, **kwargs):
+            raise AssertionError(f"{self.what} called")
+
+    class Flag:
+        @property
+        def enabled(self):
+            checks.append("registry")
+            return False
+
+    checks = []
+    tracing = obs_trace._tracing
+    monkeypatch.setattr(obs_trace, "_tracing",
+                        lambda: checks.append("profiler") or tracing())
+    monkeypatch.setattr(obs_trace, "_reg",
+                        SimpleNamespace(get_registry=Flag))
+    for name in ("time", "Span", "_log", "_stack"):
+        monkeypatch.setattr(obs_trace, name, Refuse(name))
+    with obs.span("cost") as sp:
+        pass
+    assert checks == ["registry", "profiler"]
+    assert sp is obs_trace._NULL_SPAN
+
+
+def test_program_spans_land_on_the_profilers_host_plane(tmp_path):
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.span("serve.fold", key=0):
+            with obs.span("serve.fold.priority"):
+                jnp.arange(8).sum().block_until_ready()
+        with obs.timeblock("store.snap"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(f"{tmp_path}/plugins/profile/*/*.xplane.pb")
+    host = {ev.name for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events}
+    assert {"serve.fold", "serve.fold.priority", "store.snap"} <= host
+
+
+def test_compiles_and_cache_loads_are_counted(tmp_path):
+    from jax.experimental.compilation_cache import compilation_cache
+    c = float(np.random.default_rng().integers(1, 1 << 30))
+
+    def fresh(x):
+        return x * c + 1.0
+
+    t = time.perf_counter_ns()
+    before = obs.span_log().compiles
+    jax.jit(fresh)(jnp.float32(2.0)).block_until_ready()
+    after = obs.span_log().compiles
+    assert after["jax.compile"][0] >= before["jax.compile"][0] + 1
+    assert after["jax.compile"][1] > before["jax.compile"][1]
+    assert _logged("jax.compile", t)
+
+    # the same program again, from a persistent cache: a cache load
+    prev = (jax.config.jax_compilation_cache_dir,
+            jax.config.jax_persistent_cache_min_compile_time_secs,
+            jax.config.jax_persistent_cache_min_entry_size_bytes)
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    compilation_cache.reset_cache()
+    try:
+        g = lambda x: x * c - 1.0  # noqa: E731
+        jax.jit(g)(jnp.float32(2.0)).block_until_ready()
+        jax.clear_caches()
+        mid = obs.span_log().compiles
+        jax.jit(g)(jnp.float32(2.0)).block_until_ready()
+        end = obs.span_log().compiles
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          prev[1])
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
+                          prev[2])
+        compilation_cache.reset_cache()
+    assert end["jax.cache_load"][0] >= mid["jax.cache_load"][0] + 1
+    assert end["jax.cache_load"][1] > mid["jax.cache_load"][1]
+
+
+def test_train_set_up_counts_its_own_compiles():
+    """A process that only trains (no serving, no ``repro.obs`` import
+    of its own) still has the compile listener before its first
+    compile."""
+    code = ("import jax, jax.numpy as jnp\n"
+            "from repro.train.setup import build_recsys_training\n"
+            "jax.jit(lambda x: x * 3 + 1)(jnp.ones(3)).block_until_ready()\n"
+            "from repro import obs\n"
+            "print(obs.span_log().compiles['jax.compile'][0])\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=str(pathlib.Path(__file__).resolve().parents[1]
+                              / "src"))
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert int(out.stdout.split()[-1]) >= 1
+
+
+def test_observe_logs_serve_fold_keyed_by_micro_batch():
+    obs.enable()
+    srv = OnlineServer(_store(3), CFG,
+                       OnlineConfig(cache_rows=0, retier_every=8))
+    idx = jnp.asarray((np.arange(16) % V).astype(np.int32).reshape(4, 4))
+    valid = np.array([True, True, True, False])[:, None]
+    t = time.perf_counter_ns()
+    for _ in range(3):
+        srv.observe(idx, 0, valid=valid, count=3)
+    log = [e for e in obs.span_log().spans if e[3] >= t]
+    folds = [e for e in log if e[0] == "serve.fold"]
+    assert [e[2] for e in folds] == [0, 3, 6]   # requests before each
+    for name, parent, key, s, e in folds:
+        kids = {n: (p, k, a, b) for n, p, k, a, b in log
+                if p == "serve.fold" and k == key}
+        assert set(kids) == {"serve.fold.mask", "serve.fold.priority",
+                             "serve.fold.retier"}
+        assert all(s <= a <= b <= e for _, _, a, b in kids.values())
+    # the third batch crosses request 8: its re-tier is under the fold
+    [retier] = [e for e in log if e[0] == "serve.retier"]
+    assert retier[1:3] == ("serve.fold.retier", 6)
