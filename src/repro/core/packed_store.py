@@ -28,6 +28,7 @@ import numpy as np
 from repro.core import rowwise_quant as rq
 from repro.core.qat_store import FQuantConfig, QATStore, current_tiers
 from repro.core.tiers import Tier
+from repro.kernels.rows import take_rows
 
 Array = jax.Array
 
@@ -145,7 +146,9 @@ def lookup(packed: PackedStore, indices: Array) -> Array:
 
     Three tier-local gathers + select.  The Pallas kernel in
     repro/kernels/dequant_bag fuses this with the bag reduction; this jnp
-    version is its oracle and the XLA fallback.
+    version is its oracle and the XLA fallback.  A placed store's
+    payloads (``kernels.rows.LaneDense``) are read row by row in their
+    physical view (``rows.take_rows``).
     """
     code = jnp.take(packed.indirect, indices, axis=0)
     tier = code >> _TIER_SHIFT
@@ -158,11 +161,11 @@ def lookup(packed: PackedStore, indices: Array) -> Array:
     l16 = jnp.clip(loc, 0, v16 - 1)
     l32 = jnp.clip(loc, 0, v32 - 1)
 
-    e8 = (jnp.take(packed.payload8, l8, axis=0).astype(jnp.float32)
+    e8 = (take_rows(packed.payload8, l8).astype(jnp.float32)
           * jnp.take(packed.scale8, l8, axis=0)[..., None])
-    e16 = (jnp.take(packed.payload16, l16, axis=0).astype(jnp.float32)
+    e16 = (take_rows(packed.payload16, l16).astype(jnp.float32)
            * jnp.take(packed.scale16, l16, axis=0)[..., None])
-    e32 = jnp.take(packed.payload32, l32, axis=0)
+    e32 = take_rows(packed.payload32, l32)
 
     t = tier[..., None]
     return jnp.where(t == Tier.INT8.value, e8,

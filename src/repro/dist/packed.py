@@ -36,6 +36,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core.packed_store import _IDX_MASK, _TIER_SHIFT, PackedStore
 from repro.core.tiers import Tier
 from repro.kernels import use_kernel
+from repro.kernels.rows import LaneDense, lane_dense_host
 
 Array = jax.Array
 
@@ -75,19 +76,28 @@ def shard_packed(packed: PackedStore, mesh,
 def place_packed(packed: PackedStore, mesh=None,
                  axis: str = "model") -> PackedStore:
     """Device placement matching the serving path: ``shard_packed``
-    under a mesh, plain async ``device_put`` of every leaf otherwise.
+    under a mesh; otherwise every leaf ``device_put`` from the host,
+    the three payloads as ``kernels.rows.LaneDense`` (laid out once
+    here, on the host, so the gather kernels read them as placed).
 
-    The ONE placement helper the online server and the shadow-swap
-    staging share (``serve.shadow`` pre-places the finished shadow
-    store with this before the atomic swap, so the swap itself is a
-    pointer flip, not a transfer): dispatch is asynchronous in both
-    modes — the host returns before the copy lands and jit sequences
-    the transfer before first use.
+    The ONE placement helper the online server, the hierarchical
+    store's hot level and the shadow-swap staging share (``serve.shadow``
+    pre-places the finished shadow store with this before the atomic
+    swap, so the swap itself is a pointer flip, not a transfer):
+    dispatch is asynchronous in both modes — the host returns before
+    the copy lands and jit sequences the transfer before first use.
     """
     if mesh is not None:
         return shard_packed(packed, mesh, axis)
-    return PackedStore(*(jax.device_put(np.asarray(leaf))
-                         for leaf in packed))
+
+    def put(name, leaf):
+        x = np.asarray(leaf)
+        if name.startswith("payload"):
+            return LaneDense(jax.device_put(lane_dense_host(x)), *x.shape)
+        return jax.device_put(x)
+
+    return PackedStore(*(put(name, leaf)
+                         for name, leaf in zip(PackedStore._fields, packed)))
 
 
 def shard_nbytes(packed: PackedStore, n: int) -> int:

@@ -15,6 +15,8 @@ Layout (``bag_matmul_pallas``):
   indices, scales, weights
             per-slot 1-D SMEM blocks (``kernels.rows`` slot layout)
   payload   lane-dense view in HBM (ANY); whole rows DMA'd manually
+            (a ``rows.LaneDense`` payload as placed, a logical (V, D)
+            one laid out inside the call)
   w3        (K, Dp, H_block) VMEM block: per-field first-layer weights
   out       (B_block, H_block) fp32, accumulated in-kernel
   scratch   rows  (B_block, Dp) fp32 dequantized field tile
@@ -123,7 +125,8 @@ def _bag_matmul_kernel(idx_ref, scale_ref, weight_ref, payload_ref,
 @functools.partial(jax.jit,
                    static_argnames=("block_b", "block_h", "nbuf",
                                     "scale_after", "interpret"))
-def _bag_matmul_call(payload: Array, scales: Array, indices: Array,
+def _bag_matmul_call(payload: Array, scales: Array | None,
+                     indices: Array,
                      weights: Array, w3: Array, *, block_b: int,
                      block_h: int, nbuf: int, scale_after: bool,
                      interpret: bool) -> Array:
@@ -133,7 +136,7 @@ def _bag_matmul_call(payload: Array, scales: Array, indices: Array,
     dp, r = rows.row_layout(d)
     g = rows.dma_group(payload.dtype)
     indices = indices.astype(jnp.int32)
-    sg = jnp.take(scales, indices, axis=0).astype(jnp.float32)
+    sg = rows.slot_scales(scales, indices)
     weights = weights.astype(jnp.float32)
     w3 = w3.astype(jnp.float32)
 
@@ -184,14 +187,16 @@ def _legal_block_h(block_h: int, h: int) -> int:
     return min(h, -(-block_h // rows.LANES) * rows.LANES)
 
 
-def bag_matmul_pallas(payload: Array, scales: Array, indices: Array,
+def bag_matmul_pallas(payload: Array, scales: Array | None,
+                      indices: Array,
                       weights: Array | None, w3: Array,
                       interpret: bool | None = None, *,
                       block_b: int | None = None,
                       block_h: int | None = None,
                       nbuf: int | None = None,
                       scale_after: bool = False) -> Array:
-    """payload (V, D), indices (B, K), w3 (K, D, H) -> (B, H) fp32.
+    """payload (V, D) or ``rows.LaneDense``, scales (V,) or None (unit
+    scales), indices (B, K), w3 (K, D, H) -> (B, H) fp32.
 
     One fused kernel call: gather + dequant + per-field matmul
     accumulate; the (B, K, D) fp32 rows exist only in VMEM scratch.

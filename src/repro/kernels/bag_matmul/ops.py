@@ -164,12 +164,11 @@ def packed_bag_matmul(packed: PackedStore, indices: Array, w: Array,
 
     code = jnp.take(packed.indirect, indices, axis=0)
     tier, loc = code >> _TIER_SHIFT, code & _IDX_MASK
-    ones32 = jnp.ones((packed.payload32.shape[0],), jnp.float32)
     out = jnp.zeros((b, w3.shape[-1]), jnp.float32)
     for t, payload, scales in (
             (0, packed.payload8, packed.scale8),
             (1, packed.payload16, packed.scale16),
-            (2, packed.payload32, ones32)):
+            (2, packed.payload32, None)):
         wt = (tier == t).astype(jnp.float32)
         if weights is not None:
             wt = wt * weights

@@ -13,7 +13,10 @@ Tiled layout (``dequant_bag_pallas``):
   indices, scales, weights
             per-slot 1-D SMEM blocks of B_block*K slots (padded to
             1024 words; ``kernels.rows`` slot layout)
-  payload   lane-dense (P, r*Dp) view in HBM (ANY); rows DMA'd manually
+  payload   lane-dense (P, r*Dp) view in HBM (ANY); rows DMA'd manually.
+            A ``rows.LaneDense`` payload (a placed serving store) is
+            that view already; a logical (V, D) one is laid out by XLA
+            inside the call
   out       (B_block, Dp) VMEM, accumulated in-kernel
   scratch   ``nbuf``-deep landing ring of whole physical rows (fp32) or
             packed tiles (int8 / bf16), one DMA semaphore per buffer
@@ -40,9 +43,9 @@ bit-equality isolates the *tiling* change.
 
 HBM traffic per live slot: one 128-lane physical row for fp32 tables,
 one packed (8, 128)-word tile for int8 / bf16 (the smallest DMA the
-compiler accepts at a data-dependent row), plus one relayout copy of
-the table per call into the lane-dense view.  Time on the chip: not
-measured yet (PERF.md).
+compiler accepts at a data-dependent row); a logical (V, D) payload
+adds one relayout copy of the whole table per call, a ``LaneDense``
+one none.  Time on the chip: PERF.md.
 """
 
 from __future__ import annotations
@@ -106,7 +109,7 @@ def _tiled_kernel(idx_ref, scale_ref, weight_ref, payload_ref, out_ref,
 
 @functools.partial(jax.jit,
                    static_argnames=("block_b", "nbuf", "interpret"))
-def _tiled_call(payload: Array, scales: Array, indices: Array,
+def _tiled_call(payload: Array, scales: Array | None, indices: Array,
                 weights: Array, *, block_b: int, nbuf: int,
                 interpret: bool) -> Array:
     v, d = payload.shape
@@ -114,7 +117,7 @@ def _tiled_call(payload: Array, scales: Array, indices: Array,
     dp, r = rows.row_layout(d)
     g = rows.dma_group(payload.dtype)
     indices = indices.astype(jnp.int32)
-    sg = jnp.take(scales, indices, axis=0).astype(jnp.float32)
+    sg = rows.slot_scales(scales, indices)
     weights = weights.astype(jnp.float32)
 
     nb = -(-b // block_b)
@@ -147,12 +150,14 @@ def _tiled_call(payload: Array, scales: Array, indices: Array,
     return out[:b, :d]
 
 
-def dequant_bag_pallas(payload: Array, scales: Array, indices: Array,
+def dequant_bag_pallas(payload: Array, scales: Array | None,
+                       indices: Array,
                        weights: Array | None = None,
                        interpret: bool | None = None, *,
                        block_b: int | None = None,
                        nbuf: int | None = None) -> Array:
-    """payload (V, D), scales (V,), indices (B, K) -> (B, D) fp32 bags.
+    """payload (V, D) or ``rows.LaneDense``, scales (V,) or None (unit
+    scales), indices (B, K) -> (B, D) fp32 bags.
 
     Bag-blocked kernel with an ``nbuf``-deep row-DMA landing ring over
     the lane-dense table view (``kernels.rows``).  ``block_b`` resolves

@@ -130,7 +130,7 @@ def pick_block_b(b: int, k: int, d: int, itemsize: int = 1,
     return resolve_block_b(b, k, d, itemsize, vmem_budget=vmem_budget)
 
 
-def dequant_bag_tpu(payload: Array, scales: Array, indices: Array,
+def dequant_bag_tpu(payload: Array, scales: Array | None, indices: Array,
                     weights: Array | None = None,
                     use_pallas: bool = True,
                     interpret: bool | None = None,
@@ -159,12 +159,11 @@ def packed_bag_lookup(packed: PackedStore, indices: Array,
     """
     tier, loc = _tier_split(packed, indices)
 
-    ones32 = jnp.ones((packed.payload32.shape[0],), jnp.float32)
     out = jnp.zeros((indices.shape[0], packed.dim), jnp.float32)
     for t, name, payload, scales in (
             (0, "int8", packed.payload8, packed.scale8),
             (1, "half", packed.payload16, packed.scale16),
-            (2, "fp32", packed.payload32, ones32)):
+            (2, "fp32", packed.payload32, None)):
         with jax.named_scope(f"gather_{name}"):
             w = (tier == t).astype(jnp.float32)
             if weights is not None:

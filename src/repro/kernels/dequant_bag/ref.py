@@ -6,19 +6,23 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.rows import take_rows
+
 Array = jax.Array
 
 
-def dequant_bag_ref(payload: Array, scales: Array, indices: Array,
+def dequant_bag_ref(payload: Array, scales: Array | None, indices: Array,
                     weights: Array | None = None) -> Array:
-    """payload (V, D) int8|bf16|fp32, scales (V,) fp32, indices (B, K)
-    -> bags (B, D) fp32:  out[b] = sum_k scale[i_bk] * payload[i_bk].
+    """payload (V, D) int8|bf16|fp32, scales (V,) fp32 or None (unit
+    scales), indices (B, K) -> bags (B, D) fp32:
+    out[b] = sum_k scale[i_bk] * payload[i_bk].
 
     weights: optional (B, K) per-slot weights (0 masks padding slots).
+    ``payload`` may be a ``kernels.rows.LaneDense`` (a placed store's).
     """
-    rows = jnp.take(payload, indices, axis=0).astype(jnp.float32)
-    s = jnp.take(scales, indices, axis=0)[..., None]
-    rows = rows * s
+    rows = take_rows(payload, indices).astype(jnp.float32)
+    if scales is not None:
+        rows = rows * jnp.take(scales, indices, axis=0)[..., None]
     if weights is not None:
         rows = rows * weights[..., None]
     return rows.sum(axis=1)

@@ -26,6 +26,14 @@ let XLA build the view from the column-major layout it gives narrow
 (V, 64) arrays in one transpose, with no lane-padded row-major copy
 in between.
 
+A table that is read many times and written rarely (a placed serving
+store) is held in that view for good: ``LaneDense`` carries the
+physical array and the logical ``(V, D)``, ``lane_dense_host`` builds
+the view once on the host, and ``lane_dense`` hands a ``LaneDense``'s
+array straight to the kernel.  A logical table (the training table,
+rewritten every step) is still laid out by XLA on every call; each such
+trace counts ``kernels.relayout_traced.<dtype>.<V>x<D>``.
+
 Per-slot scalars (row ids, scales, weights) are flattened to 1-D and
 streamed into SMEM one grid block at a time.  A 1-D SMEM block must be
 a multiple of 1024 words, so each block's slot list is zero-padded to
@@ -36,8 +44,11 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro import obs
 
 Array = jax.Array
 
@@ -73,14 +84,70 @@ def phys_rows(v: int, d: int, dtype) -> int:
     return -(-(-(-v // r)) // g) * g
 
 
+@jax.tree_util.register_pytree_node_class
+class LaneDense:
+    """A (V, D) table held in its lane-dense (P, r * dp) view.
+
+    The only leaf is ``phys``; the logical ``(v, d)`` is static.
+    ``shape``, ``dtype``, ``size`` and ``nbytes`` describe the logical
+    table, so code that sizes a table by them reads the same numbers
+    from either form.  ``np.asarray`` gives the logical table back.
+    """
+
+    __slots__ = ("phys", "v", "d")
+    ndim = 2
+
+    def __init__(self, phys, v: int, d: int):
+        self.phys = phys
+        self.v = int(v)
+        self.d = int(d)
+
+    def tree_flatten(self):
+        return (self.phys,), (self.v, self.d)
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(children[0], *aux)
+
+    @property
+    def shape(self) -> tuple[int, int]:
+        return (self.v, self.d)
+
+    @property
+    def dtype(self):
+        return self.phys.dtype
+
+    @property
+    def size(self) -> int:
+        return self.v * self.d
+
+    @property
+    def nbytes(self) -> int:
+        return self.size * np.dtype(self.dtype).itemsize
+
+    def __array__(self, dtype=None, copy=None):
+        dp, r = row_layout(self.d)
+        phys = np.asarray(self.phys)
+        p = phys.shape[0]
+        table = phys.reshape(p, r, dp).transpose(1, 0, 2).reshape(
+            r * p, dp)[:self.v, :self.d]
+        return table if dtype is None else table.astype(dtype)
+
+
 @jax.named_scope("lane_dense")
-def lane_dense(table: Array) -> Array:
+def lane_dense(table) -> Array:
     """(V, D) -> the (P, r * dp) physical view read by the kernels.
+    A ``LaneDense`` is already that view: its array is returned as is.
     A no-op for 32-bit tables whose D is a multiple of 128 and whose V
     is a multiple of 8."""
+    if isinstance(table, LaneDense):
+        return table.phys
     v, d = table.shape
     dp, r = row_layout(d)
     p = phys_rows(v, d, table.dtype)
+    if dp != d or p * r != v or r > 1:
+        obs.inc(f"kernels.relayout_traced.{jnp.dtype(table.dtype).name}"
+                f".{v}x{d}")
     if dp != d or p * r != v:
         table = jnp.pad(table, ((0, p * r - v), (0, dp - d)))
     if r == 1:
@@ -91,6 +158,21 @@ def lane_dense(table: Array) -> Array:
                                 for s in range(r)], axis=1)
     return jnp.transpose(table.T.reshape(dp, r, p), (2, 1, 0)).reshape(
         p, r * dp)
+
+
+def lane_dense_host(table: np.ndarray) -> np.ndarray:
+    """Numpy twin of ``lane_dense``, byte-equal to it: block ``s`` of
+    ``P`` logical rows at lanes ``[s * dp, s * dp + D)``, zeros in the
+    padding."""
+    table = np.asarray(table)
+    v, d = table.shape
+    dp, r = row_layout(d)
+    p = phys_rows(v, d, table.dtype)
+    out = np.zeros((p, r * dp), table.dtype)
+    for s in range(r):
+        block = table[s * p:(s + 1) * p]
+        out[:block.shape[0], s * dp:s * dp + d] = block
+    return out
 
 
 @jax.named_scope("from_lane_dense")
@@ -104,6 +186,22 @@ def from_lane_dense(phys: Array, v: int, d: int) -> Array:
     return phys[:v, :d]
 
 
+def take_rows(table, idx: Array) -> Array:
+    """``jnp.take(table, idx, axis=0)`` for a logical table or a
+    ``LaneDense``; the latter reads physical row ``i % P`` and its
+    segment ``i // P``, with no whole-table copy.  ``idx`` in range."""
+    if not isinstance(table, LaneDense):
+        return jnp.take(table, idx, axis=0)
+    dp, r = row_layout(table.d)
+    phys = table.phys
+    if r == 1:
+        return jnp.take(phys, idx, axis=0)[..., :table.d]
+    p = phys.shape[0]
+    both = jnp.take(phys, idx % p, axis=0).reshape(*idx.shape, r, dp)
+    seg = (idx // p)[..., None, None]
+    return jnp.take_along_axis(both, seg, axis=-2)[..., 0, :table.d]
+
+
 def legal_block_b(block_b: int) -> int:
     """Round a bag-block size up to the 8-sublane rule of the (block_b,
     ...) output tiles."""
@@ -114,6 +212,14 @@ def block_slots(n: int) -> int:
     """SMEM block length for ``n`` live slots: the next multiple of
     ``SLOT_ALIGN``."""
     return -(-n // SLOT_ALIGN) * SLOT_ALIGN
+
+
+def slot_scales(scales, indices: Array) -> Array:
+    """fp32 per-slot scales: ``scales[indices]``, or ones for
+    ``scales=None`` (a unit-scale tier needs no (V,) vector of ones)."""
+    if scales is None:
+        return jnp.ones(indices.shape, jnp.float32)
+    return jnp.take(scales, indices, axis=0).astype(jnp.float32)
 
 
 def flatten_slots(arrays, block_b: int) -> tuple[list[Array], int]:

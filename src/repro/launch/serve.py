@@ -439,9 +439,8 @@ def _main() -> None:
     packed_mib = packed_bytes / 2 ** 20
     print(f"packed {packed_mib:.2f} MiB ({packed_bytes/fp32:.1%} of fp32)")
 
-    if mesh is not None:
-        from repro.dist.packed import shard_packed, sharded_lookup
-        packed = shard_packed(packed, mesh)
+    from repro.dist.packed import place_packed, sharded_lookup
+    packed = place_packed(packed, mesh)
 
     @jax.jit
     def serve(packed, params, batch):

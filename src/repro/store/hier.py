@@ -142,12 +142,11 @@ class HierStore:
     # -- placement -----------------------------------------------------
 
     def place(self) -> None:
-        hot = PackedStore(*(jnp.asarray(leaf) for leaf in self.hot_host))
-        if self.mesh is not None:
-            from repro.dist.packed import shard_packed
-            self.hot_dev = shard_packed(hot, self.mesh, self.axis)
-        else:
-            self.hot_dev = hot
+        """``hot_dev`` through ``dist.packed.place_packed``, as the
+        shadow migration stages it: one pytree structure before and
+        after a swap."""
+        from repro.dist.packed import place_packed
+        self.hot_dev = place_packed(self.hot_host, self.mesh, self.axis)
 
     def lookup_fn(self):
         """Hot-store gather matching ``hot_dev``'s placement (the same

@@ -257,6 +257,32 @@ def test_migrate_promotes_demotes_and_stays_bit_identical(tmp_path):
         np.asarray(ps.lookup(packed2, jnp.arange(V))))
 
 
+def test_migrate_places_hot_as_shadow_stages_it(tmp_path):
+    """A synchronous migrate places the hot level as a shadow migration
+    stages it (``place_packed``): one pytree structure, so a forward
+    warmed on the staged form serves ``hot_dev`` without a compile."""
+    from repro import obs
+    from repro.dist.packed import place_packed
+    from repro.kernels.rows import LaneDense
+    st = _store(9)
+    hier, _ = _hier(st, tmp_path)
+    pri2 = np.asarray(st.priority).copy()
+    pri2[hier.cold_ids[:5]] = pri2.max() * 10
+    hier.migrate(st._replace(priority=jnp.asarray(pri2)), CFG)
+    staged = place_packed(hier.hot_host)
+    assert jax.tree.structure(hier.hot_dev) == jax.tree.structure(staged)
+    assert isinstance(hier.hot_dev.payload8, LaneDense)
+    idx = jnp.arange(hier.hot_ids.size, dtype=jnp.int32)
+    fwd = jax.jit(ps.lookup)
+    jax.block_until_ready(fwd(staged, idx))
+    before = obs.span_log().compiles["jax.compile"][0]
+    out = fwd(hier.hot_dev, idx)
+    jax.block_until_ready(out)
+    assert obs.span_log().compiles["jax.compile"][0] == before
+    np.testing.assert_array_equal(
+        np.asarray(out), np.asarray(ps.lookup(hier.hot_host, idx)))
+
+
 def test_build_requires_store_dir_for_cold():
     st = _store(10)
     b = pack(st, CFG).nbytes() // 8

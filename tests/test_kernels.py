@@ -8,6 +8,7 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 
 import repro.kernels
+from repro.kernels import rows
 from repro.core import FQuantConfig, pack
 from repro.core import packed_store as ps
 from repro.core import qat_store as qs
@@ -348,3 +349,126 @@ def test_cin_block_invariance():
     # block shape changes the fp32 accumulation order -> allclose not equal
     np.testing.assert_allclose(np.asarray(a), np.asarray(b_), rtol=1e-4,
                                atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# lane-dense placement: the view built once on the host
+
+
+LAYOUT_DTYPES = [np.int8, jnp.bfloat16, np.float32]
+
+
+def _table(v, d, dtype, seed=0):
+    x = np.random.default_rng(seed).standard_normal((v, d)) * 40
+    return x.astype(dtype)
+
+
+@pytest.mark.parametrize("dtype", LAYOUT_DTYPES, ids=["int8", "bf16", "f32"])
+@pytest.mark.parametrize("d", [32, 64, 96, 128])
+def test_lane_dense_host_byte_equal_to_lane_dense(dtype, d):
+    v = 1003
+    x = _table(v, d, dtype)
+    assert v % rows.phys_rows(v, d, x.dtype) != 0
+    want = np.asarray(rows.lane_dense(jnp.asarray(x)))
+    got = rows.lane_dense_host(x)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert got.tobytes() == want.tobytes()
+
+
+@pytest.mark.parametrize("dtype", LAYOUT_DTYPES, ids=["int8", "bf16", "f32"])
+@pytest.mark.parametrize("d", [32, 64, 96, 128])
+def test_from_lane_dense_round_trips(dtype, d):
+    v = 1003
+    x = _table(v, d, dtype, seed=1)
+    ld = rows.LaneDense(jnp.asarray(rows.lane_dense_host(x)), v, d)
+    back = rows.from_lane_dense(ld.phys, v, d)
+    assert np.asarray(back).tobytes() == x.tobytes()
+    assert np.asarray(ld).tobytes() == x.tobytes()
+
+
+def test_lane_dense_flattens_with_its_logical_shape():
+    x = _table(50, 64, np.int8)
+    ld = rows.LaneDense(jnp.asarray(rows.lane_dense_host(x)), 50, 64)
+    leaves, tree = jax.tree.flatten(ld)
+    assert len(leaves) == 1 and leaves[0] is ld.phys
+    back = jax.tree.unflatten(tree, leaves)
+    assert isinstance(back, rows.LaneDense)
+    assert (back.v, back.d) == (50, 64) and back.phys is ld.phys
+    # the logical shape is static: another V is another structure
+    other = rows.LaneDense(ld.phys, 49, 64)
+    assert jax.tree.structure(other) != tree
+    # and a jitted function sees it through a trace
+    got = jax.jit(lambda t: rows.take_rows(t, jnp.arange(50)))(ld)
+    assert np.asarray(got).tobytes() == x.tobytes()
+
+
+def test_lane_dense_reports_the_logical_table():
+    v, d = 77, 24
+    x = _table(v, d, jnp.bfloat16)
+    ld = rows.LaneDense(jnp.asarray(rows.lane_dense_host(x)), v, d)
+    assert ld.phys.shape != (v, d)
+    assert ld.shape == (v, d) and ld.ndim == 2 and ld.size == v * d
+    assert ld.dtype == x.dtype
+    assert ld.nbytes == x.nbytes
+    assert rows.lane_dense(ld) is ld.phys
+
+
+def test_relayout_counter_counts_logical_tables_only():
+    from repro import obs
+    x = jnp.asarray(_table(100, 64, np.int8))
+    ld = rows.LaneDense(jnp.asarray(rows.lane_dense_host(np.asarray(x))),
+                        100, 64)
+    name = "kernels.relayout_traced.int8.100x64"
+    with obs.bind(obs.Registry()) as reg:
+        rows.lane_dense(ld)
+        assert reg.counters.get(name, 0) == 0
+        rows.lane_dense(x)
+        assert reg.counters[name] == 1
+        # a 32-bit table already lane-dense needs no relayout
+        rows.lane_dense(jnp.zeros((64, 128), jnp.float32))
+        assert set(reg.counters) == {name}
+
+
+def _three_tier_store(d, seed=0):
+    cfg = FQuantConfig(stochastic=False)
+    stt = qs.init(jax.random.PRNGKey(seed), 96, d, scale=0.05)
+    pri = jnp.concatenate([jnp.zeros(32), jnp.full(32, 1e4),
+                           jnp.full(32, 1e6)])
+    stt = stt._replace(priority=pri)
+    stt = stt._replace(table=qs.snap(stt.table,
+                                     qs.current_tiers(stt, cfg), cfg))
+    return pack(stt, cfg)
+
+
+@pytest.mark.parametrize("d", [24, 64])
+def test_placed_store_reads_bit_equal_to_logical(d):
+    """Over a store from ``place_packed`` every reader gives the bytes
+    it gives over the logical host store: both kernels (interpreted)
+    and the jnp oracles."""
+    from repro.dist.packed import place_packed
+    from repro.kernels.bag_matmul.ops import packed_bag_matmul
+    host = _three_tier_store(d)
+    placed = place_packed(host)
+    assert all(isinstance(p, rows.LaneDense) for p in
+               (placed.payload8, placed.payload16, placed.payload32))
+    assert placed.nbytes() == host.nbytes()
+    assert (placed.vocab, placed.dim) == (host.vocab, host.dim)
+    rng = np.random.default_rng(3)
+    idx = jnp.asarray(rng.integers(0, 96, (9, 5)).astype(np.int32))
+    w = jnp.asarray(rng.uniform(0, 1, (9, 5)).astype(np.float32))
+    w3 = jnp.asarray(rng.standard_normal((5, d, 16)).astype(np.float32))
+
+    def same(fn):
+        a, b = np.asarray(fn(placed)), np.asarray(fn(host))
+        assert a.dtype == b.dtype and a.tobytes() == b.tobytes()
+
+    same(lambda s: packed_bag_lookup(s, idx, weights=w, use_pallas=True))
+    same(lambda s: packed_bag_lookup(s, idx, weights=w, use_pallas=False))
+    same(lambda s: packed_lookup_fused(s, idx, use_pallas=True))
+    same(lambda s: ps.lookup(s, idx))
+    same(lambda s: jax.jit(ps.lookup)(s, idx))
+    same(lambda s: packed_bag_matmul(s, idx, w3, use_pallas=True))
+    same(lambda s: packed_bag_matmul(s, idx, w3, weights=w,
+                                     use_pallas=False))
+    same(lambda s: ps.bag_lookup(s, idx.reshape(-1),
+                                 jnp.repeat(jnp.arange(9), 5), 9))

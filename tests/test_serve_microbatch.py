@@ -167,3 +167,34 @@ def test_microbatch_stream_independent_of_fusion_factor():
             got.append(tail.indices[:tail.count])
         np.testing.assert_array_equal(np.concatenate(got),
                                       np.stack(reqs))
+
+
+def test_relayout_traced_zero_for_placed_forward_not_for_train_step(
+        monkeypatch):
+    """``kernels.relayout_traced`` on the kernel path: the micro-batched
+    forward over the placed store traces no whole-table relayout, the
+    same forward over (V, D) payloads one per tier, and the compressed
+    train step (a logical table, rewritten every step) keeps its own."""
+    from repro import obs
+    from repro.configs import dlrm_rm2
+    from repro.kernels.rows import LaneDense
+    from repro.train.setup import build_recsys_training
+    from _smoke_serve import kernel_path, logical, smoke_forward
+
+    server, fwd, (packed, *rest) = smoke_forward()
+    assert isinstance(packed.payload8, LaneDense)
+    train = build_recsys_training(dlrm_rm2.arch(), batch=16,
+                                  use_pallas=True)
+    batch = train.batch_fn(0)
+
+    def relayouts(fn, *args):
+        with obs.bind(obs.Registry()) as reg:
+            jaxpr = str(fn.trace(*args).jaxpr)
+        assert "pallas_call" in jaxpr
+        return {k: v for k, v in reg.counters.items()
+                if k.startswith("kernels.relayout_traced.")}
+
+    with kernel_path(monkeypatch):
+        assert relayouts(fwd, packed, *rest) == {}
+        assert len(relayouts(fwd, logical(packed), *rest)) == 3
+        assert relayouts(jax.jit(train.step), train.state, batch)

@@ -200,6 +200,51 @@ def test_swap_during_drift_lands_snapshot_state():
         np.asarray(ps.unpack(pack(final, CFG))))
 
 
+def _placed_like_fresh(server) -> bool:
+    """The live device store has the pytree structure a fresh placement
+    of the live host pack has, payloads lane-dense."""
+    from repro.dist.packed import place_packed
+    from repro.kernels.rows import LaneDense
+    pk = server.packed
+    return (jax.tree.structure(pk)
+            == jax.tree.structure(place_packed(server.host_packed))
+            and all(isinstance(p, LaneDense) for p in
+                    (pk.payload8, pk.payload16, pk.payload32)))
+
+
+def test_swap_keeps_placed_layout_and_compiles_nothing():
+    """The shadow is staged as the live store is placed, so the forward
+    warmed on the staged store while staging serves the swapped-in
+    store without a compile (the compile counter of ``obs.span_log``)."""
+    from repro import obs
+    from repro.serve import cached_lookup
+    rng = np.random.default_rng(11)
+    server = _flat_server(seed=1)
+    lfn = server.lookup_fn()
+    idx = jnp.asarray(rng.integers(0, V, (32,)).astype(np.int32))
+    fwd = jax.jit(lambda pk, cache, i: cached_lookup(pk, cache, i,
+                                                     lfn)[0])
+    server.warmup_fn = lambda staged: jax.block_until_ready(
+        fwd(staged, server.cache, idx))
+    assert _placed_like_fresh(server)
+    jax.block_until_ready(fwd(server.packed, server.cache, idx))
+    for _ in range(6):      # drift until some rows cross tiers
+        server.observe(jnp.asarray(rng.integers(0, V, (64,))
+                                   .astype(np.int32)), count=16)
+    shapes = [p.shape for p in server.packed]
+    assert server.begin_retier()
+    server.drain_shadow()
+    assert server.stats.swaps == 1
+    assert [p.shape for p in server.packed] != shapes
+    assert _placed_like_fresh(server)
+    before = obs.span_log().compiles["jax.compile"][0]
+    out = fwd(server.packed, server.cache, idx)
+    jax.block_until_ready(out)
+    assert obs.span_log().compiles["jax.compile"][0] == before
+    np.testing.assert_array_equal(np.asarray(out),
+                                  _mirror(server)[np.asarray(idx)])
+
+
 def test_double_swap_and_crash_before_swap():
     rng = np.random.default_rng(23)
     server = _flat_server(seed=2)

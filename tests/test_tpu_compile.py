@@ -4,15 +4,21 @@ Each test lowers a kernel with ``interpret=False`` against a described
 (not attached) v5e chip and compiles it with the TPU compiler, which
 refuses what the interpreter accepts: lane-misaligned slices, packed
 row offsets, SMEM overflow, block shapes off the 8 x 128 tile rules.
-Nothing runs, so these say nothing about results or speed.
+Nothing runs, so these say nothing about results or speed.  One test
+compiles the whole micro-batched serve forward (smoke widths) to check
+that a placed store reaches the kernels with no table relayout.
 
 The topology is described inside a module fixture, never at import:
 only one process at a time may load the TPU compiler's library, and
 every test worker imports this file.
 """
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -106,3 +112,61 @@ def test_rowwise_quant_compiles(one_chip):
     _compile(one_chip,
              lambda x: quantize_rowwise_pallas(x, interpret=False),
              ((65536, D), jnp.float32))
+
+
+# what moves an argument's bytes as they are into faster memory (XLA's
+# prefetches), beside parameters and the kernels themselves
+_NOT_RELAYOUT = ("parameter", "custom-call", "copy-start", "copy-done",
+                 "slice-start", "slice-done")
+
+
+def _table_ops(hlo: str, table_elements: int) -> list[str]:
+    """Instructions of the compiled program, outside fusion bodies,
+    whose output holds ``table_elements`` or more elements, other than
+    ``_NOT_RELAYOUT``: ``relayout_ms.serve``'s rule, with "a table's
+    worth" scaled to the tables compiled."""
+    fused = set(re.findall(r"calls=%([\w.\-]+)", " ".join(
+        line for line in hlo.splitlines() if " fusion(" in line)))
+    found, skip = [], False
+    for line in hlo.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            skip = line.split(" ")[0].lstrip("%") in fused
+            continue
+        inst = line.strip().removeprefix("ROOT ")
+        if skip or not inst.startswith("%") or " = " not in inst:
+            continue
+        rest = re.sub(r"\{[^}]*\}", "", inst.partition(" = ")[2])
+        out = (rest[:rest.find(")") + 1] if rest.startswith("(")
+               else rest.split(" ")[0])
+        n = sum(math.prod(int(d) for d in dims.split(",") if d)
+                for dims in re.findall(r"\[([\d,]*)\]", out))
+        opcode = rest[len(out):].strip().partition("(")[0]
+        if n >= table_elements and opcode not in _NOT_RELAYOUT:
+            found.append(inst)
+    return found
+
+
+def test_placed_serve_forward_relays_no_table(one_chip, monkeypatch):
+    """The micro-batched serve forward over a placed store, compiled
+    for a v5e, holds no op as large as a tier table; over (V, D)
+    payloads it lays out every tier on every call."""
+    from _smoke_serve import kernel_path, logical, smoke_forward
+
+    server, fwd, (packed, *rest) = smoke_forward()
+    table = min(int(np.prod(p.shape)) for p in
+                (packed.payload8, packed.payload16, packed.payload32))
+
+    def shapes(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    def table_ops(store):
+        hlo = fwd.lower(shapes(store), *shapes(rest)).compile().as_text()
+        assert "tpu_custom_call" in hlo
+        return _table_ops(hlo, table)
+
+    with kernel_path(monkeypatch):
+        assert table_ops(packed) == []
+        relaid = " ".join(table_ops(logical(packed)))
+    for tier in ("gather_int8", "gather_half", "gather_fp32"):
+        assert f"{tier}/jit(_tiled_call)/lane_dense" in relaid
