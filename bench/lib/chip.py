@@ -27,8 +27,11 @@ def use_compile_cache() -> str:
 
 
 def require_chips(n: int) -> list:
-    """The TPU devices, or NoChip: no CPU fallback, no interpreted
-    kernels, no fewer chips than the cell asks for."""
+    """The first ``n`` TPU devices, or NoChip: no CPU fallback, no
+    interpreted kernels, no fewer chips than the cell asks for.  They
+    are the cell's chips: a serve cell on more than one forms its
+    ``"model"`` mesh from them (``Ctx.mesh``); a train cell takes one
+    chip and refuses more."""
     import jax
     try:
         devices = jax.devices()

@@ -4,6 +4,7 @@ metrics."""
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib
 import time
 
@@ -53,6 +54,16 @@ class Ctx:
     @property
     def platform(self) -> str:
         return self.devices[0].platform
+
+    @functools.cached_property
+    def mesh(self):
+        """The cell's chips as a 1-D ``"model"`` mesh, over which the
+        serving store and the tables are row-sharded; None on one
+        chip, where every call is the one-device call."""
+        if len(self.devices) == 1:
+            return None
+        from jax.sharding import Mesh
+        return Mesh(np.array(self.devices), ("model",))
 
     def reference(self):
         return registry.reference(self.cfg)

@@ -11,6 +11,13 @@ which compiles the forward and returns it jitted
 (``LoopResult.forward``).  Then one harness micro-batch warms the same
 program and the fold at the window's shapes.
 
+Chips: a cell on more than one chip passes its ``"model"`` mesh
+(``Ctx.mesh``) to ``OnlineServer``, so the store is row-sharded over
+the cell's devices and served through the program's sharded lookup;
+the weights are drawn with the table row-sharded and every other leaf
+replicated.  On one chip there is no mesh and every call is the
+one-device call.
+
 Window: per micro-batch, the few lines of ``serve_forward``'s glue,
 copied: the batch dict, the jitted forward with ``block_until_ready``,
 and ``OnlineServer.observe``.  The requests come from
@@ -51,12 +58,12 @@ def setup(ctx: Ctx):
     sizes = ctx.sizes
     model = build_model(ctx.cfg, sizes)
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    params = weights.make(shapes, ctx.cfg["init"], ctx.seed)
+    params = weights.make(shapes, ctx.cfg["init"], ctx.seed, ctx.mesh)
     table = params.pop(ctx.cfg["program"]["table"])
     store, fq = build_serving_store(
         model.spec, table, seed=ctx.cfg["serving_store"]["priority"]["seed"])
     del table
-    server = OnlineServer(store, fq, online=OnlineConfig())
+    server = OnlineServer(store, fq, online=OnlineConfig(), mesh=ctx.mesh)
     del store
     nd = int(sizes.get("num_dense", 0))
     mb = int(ctx.mix["micro_batch"])
@@ -173,12 +180,14 @@ def _open(ctx: Ctx, st: Served) -> None:
 
 
 def footprint(ctx: Ctx, st: Served) -> None:
-    """Served bytes on the device once the window closed, with the
-    harness's own set-up arrays dropped."""
+    """Served bytes on the fullest of the cell's devices once the window
+    closed, with the harness's own set-up arrays dropped: what decides
+    whether the store fits its chips."""
     from bench.lib.chip import bytes_in_use
     st.labels = None
     gc.collect()
-    ctx.e2e["serve_hbm_gib"] = bytes_in_use(ctx.devices[0]) / 2 ** 30
+    ctx.e2e["serve_hbm_gib"] = max(
+        bytes_in_use(d) for d in ctx.devices) / 2 ** 30
 
 
 def check(ctx: Ctx, st: Served) -> None:
@@ -256,14 +265,38 @@ def _program_rows(st: Served, idx):
         for i in range(0, gidx.shape[0], _REF_CHUNK)])
 
 
+def take_rows(mesh):
+    """``jnp.take`` of table rows.  Under a mesh the table is
+    row-sharded: each device reads the rows of its own shard, zeros for
+    the rest, and one psum assembles them (exact: the other devices add
+    zeros), so no device ever holds the whole table."""
+    import jax
+    import jax.numpy as jnp
+    if mesh is None:
+        return lambda t, g: jnp.take(t, g, axis=0)
+    from jax.sharding import PartitionSpec as P
+    axis = mesh.axis_names[0]
+
+    def local(t, g):
+        v = t.shape[0]
+        loc = g - jax.lax.axis_index(axis) * v
+        mine = (loc >= 0) & (loc < v)
+        rows = jnp.take(t, jnp.clip(loc, 0, v - 1), axis=0)
+        return jax.lax.psum(jnp.where(mine[..., None], rows, 0.0), axis)
+
+    return jax.shard_map(local, mesh=mesh, in_specs=(P(axis, None), P()),
+                         out_specs=P())
+
+
 def reference_rows(ctx: Ctx, shapes, tiers, cards, idx):
     import jax
     import jax.numpy as jnp
-    table = weights.make(shapes, ctx.cfg["init"], ctx.seed)[
+    table = weights.make(shapes, ctx.cfg["init"], ctx.seed, ctx.mesh)[
         ctx.cfg["program"]["table"]]
     gidx = jnp.asarray(global_ids(cards, idx))
+    take = take_rows(ctx.mesh)
     rows = jax.jit(lambda t, g: shark_ref.served_rows(
-        jnp.take(t, g, axis=0), jnp.take(tiers, g, axis=0)))
+        take(t, g), jnp.take(tiers, g, axis=0)))
     return np.concatenate([np.asarray(rows(table, gidx[i:i + _REF_CHUNK]))
                            for i in range(0, gidx.shape[0], _REF_CHUNK)])
 
@@ -277,15 +310,16 @@ def reference_logits(ctx: Ctx, shapes, tiers, cards, idx, dense,
     import jax.numpy as jnp
 
     ref = ctx.reference()
-    params = weights.make(shapes, ctx.cfg["init"], ctx.seed)
+    params = weights.make(shapes, ctx.cfg["init"], ctx.seed, ctx.mesh)
     table = params.pop(ctx.cfg["program"]["table"])
     gidx = global_ids(cards, idx)
     dot = shark_ref.make_dot(precision)
+    take = take_rows(ctx.mesh)
 
     @jax.jit
     def head(params, table, tiers, gidx, dense, emb):
         if emb is None:
-            emb = shark_ref.served_rows(jnp.take(table, gidx, axis=0),
+            emb = shark_ref.served_rows(take(table, gidx),
                                         jnp.take(tiers, gidx, axis=0))
         batch = {"gidx": gidx}
         if dense is not None:

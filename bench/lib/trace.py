@@ -1,22 +1,25 @@
 """Reduce a profiler trace to the numbers the per-layer metrics read.
 
 Input: the ``.xplane.pb`` the JAX profiler writes (read with
-``jax.profiler.ProfileData``, nothing else).  Device planes are those
-named ``/device:TPU:<n>``; a device's operations are the events of its
+``jax.profiler.ProfileData``, nothing else), and the ids of the cell's
+devices.  Device planes are those named ``/device:TPU:<id>``; only the
+cell's own are read, so a cell on fewer chips than its host holds
+averages in no idle chip.  A device's operations are the events of its
 ``XLA Ops`` line.  Harness spans are the ``TraceAnnotation`` events of
 the host's threads whose names the harness gave (``bench.lib.spans``).
 
 Output (``Reduced``), inside the harness's ``window`` span:
   busy_s        union of the intervals in which an operation ran,
-                averaged over the devices
+                averaged over the cell's devices
   window_s      length of the traced window
-  op_s          per-name sums of operation time, over all devices
-                (innermost operations only: a loop's event spans its
-                body's)
+  op_s          per-name sums of operation time, over the cell's
+                devices (innermost operations only: a loop's event
+                spans its body's)
   ops           (name, start_ns, end_ns, jitted program) of every
-                innermost operation, all devices
-  gap_s         idle time of device 0 attributed to the innermost
-                harness span open at each gap's midpoint
+                innermost operation, the cell's devices
+  gap_s         idle time of the cell's first device attributed to the
+                innermost harness span open at each gap's midpoint
+  devices       the number of the cell's devices
 """
 
 from __future__ import annotations
@@ -53,7 +56,7 @@ class Reduced:
         return 1.0 - self.busy_s / self.window_s
 
     def op_time(self, match, module: str | None = None) -> float:
-        """Summed device seconds, over all devices, of the operations
+        """Summed device seconds, over the cell's devices, of the operations
         ``match(name)`` accepts; with ``module``, only those run by the
         jitted program of that name (``jit_fwd``, ``jit_step``)."""
         if module is None:
@@ -198,11 +201,14 @@ def _module_of(modules, starts, t) -> str:
     return ""
 
 
-def reduce(profile, span_names) -> Reduced:
+def reduce(profile, span_names, device_ids) -> Reduced:
+    """``device_ids``: the ids of the cell's devices (``jax.Device.id``),
+    the first of them the one whose gaps are attributed."""
     span_names = set(span_names) | {WINDOW}
+    wanted = [f"{DEVICE_PREFIX}{i}" for i in device_ids]
     spans, devices = [], []
     for plane in profile.planes:
-        if plane.name.startswith(DEVICE_PREFIX):
+        if plane.name in wanted:
             lines = {ln.name: [(ev.name, ev.start_ns,
                                 ev.start_ns + ev.duration_ns)
                                for ev in ln.events] for ln in plane.lines}
@@ -216,10 +222,11 @@ def reduce(profile, span_names) -> Reduced:
     windows = [s for s in spans if s[0] == WINDOW]
     if not windows:
         raise ValueError("trace holds no harness 'window' span")
-    if not devices:
-        raise ValueError("trace holds no TPU device plane")
+    missing = set(wanted) - {d[0] for d in devices}
+    if missing:
+        raise ValueError(f"trace holds no plane for {sorted(missing)}")
     w0, w1 = windows[0][1], windows[0][2]
-    devices.sort(key=lambda d: int(d[0][len(DEVICE_PREFIX):] or 0))
+    devices.sort(key=lambda d: wanted.index(d[0]))
     op_s = defaultdict(float)
     busy, tagged = [], []
     first = None

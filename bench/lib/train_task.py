@@ -9,6 +9,9 @@ through the first ``check_steps`` steps with the window's own feed and
 call.  Their readings (each step's loss, the first gradient as the
 optimizer holds it, the parameters' change) are taken there; the
 window then continues from the state they left.
+
+A train cell runs on one chip: set-up refuses more, since training
+under a mesh is not what the harness measures.
 """
 
 from __future__ import annotations
@@ -55,6 +58,10 @@ def setup(ctx: Ctx):
     from repro import configs
     from repro.train.setup import build_recsys_training
 
+    if len(ctx.devices) != 1:
+        raise ValueError(f"a train cell takes one chip, {ctx.cell['name']}"
+                         f" was given {len(ctx.devices)}: the harness has"
+                         " no sharded training")
     tkey = ctx.cfg["program"]["table"]
     arch = configs.get(ctx.cfg["program"]["arch"])
     prog = build_recsys_training(arch, batch=int(ctx.mix["batch"]),
@@ -169,12 +176,15 @@ def check(ctx: Ctx, st: Trained) -> None:
 
 
 def gaps(got: dict, ref: dict) -> dict:
-    """The compared numbers: each step's loss, the first gradient and
-    the change after the checked steps, both by the worst counted
-    leaf, as a share of that leaf's reference norm or the median
-    leaf's, whichever is larger."""
-    lp, lr = np.asarray(got["losses"]), np.asarray(ref["losses"])
-    loss = float(np.max(np.abs(lp - lr) / np.abs(lr)))
+    """The compared numbers: the first step's loss, relative; the first
+    gradient and the change after the checked steps, both by the worst
+    counted leaf, as a share of that leaf's reference norm or the median
+    leaf's, whichever is larger.  The later steps' losses are not
+    compared: they start from parameters that differ by the first
+    update's round-off, and swing from seed to seed about as far as the
+    control's do; the change after them is compared instead."""
+    loss = float(abs(got["losses"][0] - ref["losses"][0])
+                 / abs(ref["losses"][0]))
     g_ref = ref["grad1"]
     med_g = float(np.median(list(g_ref.values())))
     counted = [k for k, v in g_ref.items() if v >= LEAF_FLOOR * med_g]
@@ -185,7 +195,7 @@ def gaps(got: dict, ref: dict) -> dict:
                    for k in keys)
 
     head = [k for k in counted if k in got["grad1"]]
-    return {"loss_gap": loss,
+    return {"loss1_gap": loss,
             "grad_gap": worst(got["grad1"], g_ref, head),
             "change_gap": worst(got["change"], ref["change"], counted)}
 

@@ -1,9 +1,11 @@
 """Share of the HBM roofline the serving gather kernels reach: the
-bytes the three per-tier ``dequant_bag`` calls need (each reads its
-tier's rows, every slot's id, scale and weight words, and writes the
-(slots, D) fp32 output) over their summed device time, against the
-chip's HBM bandwidth.  Bytes per call as ``_bytes_dequant`` counts
-them."""
+bytes the per-tier ``dequant_bag`` calls need over their device time
+summed over the cell's chips, against one chip's HBM bandwidth.  Each
+chip makes one call per tier; every call reads every slot's id, scale
+and weight words and writes the (slots, D) fp32 output, while each row
+is read once, by the call of its tier on the chip that holds it (a
+slot another chip owns has weight 0 and no DMA).  Bytes per call as
+``_bytes_dequant`` counts them."""
 
 from bench.lib.peaks import chip_peaks
 
@@ -21,9 +23,9 @@ def is_kernel(name: str) -> bool:
     return KERNEL in op and OTHER not in op
 
 
-def bytes_needed(slots_by_tier, dim: int) -> int:
+def bytes_needed(slots_by_tier, dim: int, chips: int) -> int:
     slots = sum(slots_by_tier)
-    return sum(n * dim * it + slots * 12 + slots * dim * 4
+    return sum(n * dim * it + chips * (slots * 12 + slots * dim * 4)
                for n, it in zip(slots_by_tier, ITEMSIZE))
 
 
@@ -33,4 +35,5 @@ def read(ctx):
     if t <= 0 or not tiers:
         return None
     bw = chip_peaks(ctx.devices[0].device_kind)["hbm_bw"]
-    return bytes_needed(tiers, ctx.sizes["embed_dim"]) / t / bw * 100.0
+    nbytes = bytes_needed(tiers, ctx.sizes["embed_dim"], len(ctx.devices))
+    return nbytes / t / bw * 100.0

@@ -1,9 +1,11 @@
-"""Device time per micro-batch of the whole-table passes in the jitted
-program: the lane-dense view of the three tier tables that XLA builds in the
-forward on every call (``kernels/rows.lane_dense``).  Counted: operations of that program,
-other than the Pallas kernels, whose output holds a table's worth of
-elements (2**20 or more); the per-request work (head, index math) is
-smaller by orders of magnitude."""
+"""Device time per micro-batch and per chip of the whole-table passes
+in the jitted program: the lane-dense view of the tier tables that XLA
+builds in the forward on every call (``kernels/rows.lane_dense``) where
+the store is not placed lane-dense, as the row-sharded store's shards
+are not.  Counted: operations of that program, other than the Pallas
+kernels, whose output holds a table's worth of elements (2**20 or
+more); the per-request work (head, index math) is smaller by orders of
+magnitude.  Summed over the cell's devices, divided by their number."""
 
 import math
 import re
@@ -28,4 +30,4 @@ def is_relayout(name: str) -> bool:
 def read(ctx):
     n = ctx.counts.get("batches")
     t = ctx.trace_data.op_time(is_relayout, module=MODULE)
-    return t / n * 1e3 if n and t > 0 else None
+    return t / ctx.trace_data.devices / n * 1e3 if n and t > 0 else None

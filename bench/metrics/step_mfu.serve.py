@@ -1,6 +1,7 @@
-"""The whole serve step's share of the chip: requests/s in the traced
-window times the least time one request needs, the larger of its
-FLOPs over peak FLOP/s and its minimal bytes over HBM bandwidth.
+"""The whole serve step's share of the cell's chips: requests/s in the
+traced window times the least time one request needs, the larger of
+its FLOPs over peak FLOP/s and its minimal bytes over HBM bandwidth,
+over the number of chips (each chip's peaks, times the chips).
 Minimal bytes: the request's rows as their tiers hold them (payload and
 scale), its inputs, and the head's weights read once per micro-batch.
 For these models the bytes bound it."""
@@ -27,5 +28,5 @@ def read(ctx):
     peaks = chip_peaks(ctx.devices[0].device_kind)
     flops, nbytes = per_request(ctx)
     qps = ctx.counts["requests"] / ctx.counts["window_s"]
-    return qps * max(flops / peaks["flops"],
-                     nbytes / peaks["hbm_bw"]) * 100.0
+    return qps * max(flops / peaks["flops"], nbytes / peaks["hbm_bw"]) \
+        / len(ctx.devices) * 100.0

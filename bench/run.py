@@ -14,7 +14,11 @@ last key of the JSON line.
 
 It runs only on TPU chips: with no TPU, fewer chips than the cell asks
 for, or Pallas interpretation forced, it exits non-zero and prints no
-result.  JAX's compile cache lives at ``<checkout>/.bench_cache/jax``.
+result.  A cell's ``chips`` are the first that many devices; a serve
+cell on more than one forms its ``"model"`` mesh from them and serves
+from a store row-sharded over them, and a train cell takes one chip.
+The per-chip metrics read the cell's devices alone.  JAX's compile
+cache lives at ``<checkout>/.bench_cache/jax``.
 """
 
 from __future__ import annotations
@@ -79,7 +83,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, devices,
         from bench.lib import trace as tr
         prof = tr.load(tr.find_xplane(str(TRACE_DIR)))
         ctx.trace_data = tr.reduce(prof, {n for n, _, _ in
-                                          ctx.spans.records})
+                                          ctx.spans.records},
+                                   [d.id for d in devices])
         del prof
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
         device["busy_s"] = ctx.trace_data.busy_s

@@ -28,7 +28,7 @@ def _window(profile):
 @pytest.fixture(scope="module")
 def recorded():
     profile = tr.load(DATA)
-    return tr.reduce(profile, SPANS), _window(profile)
+    return tr.reduce(profile, SPANS, [0]), _window(profile)
 
 
 def _ctx(recorded):

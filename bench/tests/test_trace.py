@@ -3,6 +3,7 @@ traced window of ``dlrm-rm2.serve-zipf.sat``) and on hand-made
 intervals."""
 
 import os
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -27,7 +28,7 @@ def test_union_and_innermost_by_hand():
 
 @pytest.fixture(scope="module")
 def reduced():
-    return tr.reduce(tr.load(DATA), SPANS)
+    return tr.reduce(tr.load(DATA), SPANS, [0])
 
 
 def test_recorded_trace_busy_and_idle(reduced):
@@ -63,6 +64,39 @@ def test_recorded_trace_names_the_kernels(reduced):
     assert reduced.op_time(relayout.is_relayout, module="jit_fwd") \
         > 0.8 * fwd * 1e-9
     assert set(reduced.gap_s) <= SPANS | {tr.NO_SPAN}
+
+
+# the excerpt's busy and window seconds as the reduction read every
+# device plane of a profile, before it took the cell's device ids
+BUSY_S, WINDOW_S = 0.212959897, 0.25
+
+
+def _with_idle_second_device(profile):
+    """The excerpt as a two-chip host's profile would hold it for a
+    one-chip cell: a second device plane on which nothing ran."""
+    idle = tr._Plane(tr.DEVICE_PREFIX + "1", [tr._Line(tr.OPS_LINE, [])])
+    return SimpleNamespace(planes=list(profile.planes) + [idle])
+
+
+def test_reduce_reads_only_the_cells_devices(reduced):
+    assert (reduced.busy_s, reduced.window_s) == pytest.approx(
+        (BUSY_S, WINDOW_S), rel=1e-12)
+    assert reduced.idle_share == pytest.approx(1 - BUSY_S / WINDOW_S,
+                                               rel=1e-12)
+    host = _with_idle_second_device(tr.load(DATA))
+    own = tr.reduce(host, SPANS, [0])
+    assert (own.devices, own.busy_s, own.window_s, own.op_s, own.gap_s) \
+        == (1, reduced.busy_s, reduced.window_s, reduced.op_s,
+            reduced.gap_s)
+    both = tr.reduce(host, SPANS, [0, 1])
+    assert both.devices == 2
+    assert both.busy_s == pytest.approx(reduced.busy_s / 2, rel=1e-12)
+    assert both.op_s == reduced.op_s
+
+
+def test_reduce_refuses_a_device_the_trace_lacks():
+    with pytest.raises(ValueError, match="TPU:1"):
+        tr.reduce(tr.load(DATA), SPANS, [0, 1])
 
 
 def test_leaves_drop_loop_events():
